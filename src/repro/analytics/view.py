@@ -84,7 +84,7 @@ def _collect_sorted(snapshot: Snapshot):
     arrival order and are sorted individually.  Any 2..TOURNAMENT_MAX_SOURCES
     pre-sorted sources (deep snapshots included) merge on-device via the
     log-k tournament of pairwise merge-path passes; beyond that one host
-    lexsort remains (the TPU path would be a bitonic sort, csr._merge_impl).
+    lexsort remains (the TPU path would be a device sort, csr._sort_records).
     Sources with no record visible at τ are skipped up front — they can
     only add dead weight to the merge."""
     sources = []

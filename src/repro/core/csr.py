@@ -2,10 +2,12 @@
 
 Every function here is pure and jit-able over fixed-capacity arrays.  A run is
 always sorted by (src, dst, ts); invalid slots carry src == INVALID_VID so they
-sort to the tail.  The k-way compaction merge is realized as concat + lexsort —
-on the TPU a bitonic sort of the concatenated runs is the fast path (DESIGN.md
-§2); the Pallas two-way merge kernel (kernels/merge.py) covers the common
-two-run case.
+sort to the tail.  The k-way compaction merge is realized as concat + sort.
+
+Record sorts run on the host (``lexsort_order``): a multi-key device sort
+takes minutes to compile for a TPU at store sizes and recompiles for every
+capacity bucket, while moving the keys across takes milliseconds.  Every
+other step (gathers, GC, CSR offsets) stays on the device, sort-free.
 """
 from __future__ import annotations
 
@@ -14,16 +16,66 @@ from typing import Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .types import INVALID_VID, CSRRunArrays
 
 
-def _lexsort_edges(src: jnp.ndarray, dst: jnp.ndarray, ts: jnp.ndarray) -> jnp.ndarray:
+def lexsort_order(*keys: jnp.ndarray) -> jnp.ndarray:
+    """Stable permutation sorting records by ``keys`` (most significant
+    first), computed by numpy and returned on the keys' device.
+
+    Compiling ``jnp.lexsort`` over three int32 keys for one TPU v5e chip
+    (on an x86 host CPU) takes 228 s at 4M records and 127 s at 64K, once
+    per capacity bucket — longer than a whole scale-18 smoke run's device
+    work."""
+    order = np.lexsort([np.asarray(k) for k in keys[::-1]]).astype(np.int32)
+    return jax.device_put(order, keys[0].sharding)
+
+
+def _lexsort_edges(src, dst, ts) -> jnp.ndarray:
     """Order: src asc, then dst asc, then ts asc. Returns permutation."""
-    return jnp.lexsort((ts, dst, src))
+    return lexsort_order(src, dst, ts)
 
 
 @functools.partial(jax.jit, static_argnames=("vcap",))
+def build_sorted_run_arrays(src_s, dst_s, ts_s, marker_s, prop_s, n, *,
+                            vcap: int) -> CSRRunArrays:
+    """CSR run from records already (src, dst, ts)-sorted with the n valid
+    ones first.  No sort: the vertex keys are the change points of src."""
+    ecap = src_s.shape[0]
+    pos = jnp.arange(ecap, dtype=jnp.int32)
+    valid = pos < n
+    src_s = jnp.where(valid, src_s, INVALID_VID)
+    first = valid & ((pos == 0) | (src_s != jnp.roll(src_s, 1)))
+    starts = jnp.nonzero(first, size=vcap, fill_value=ecap)[0]
+    starts = starts.astype(jnp.int32)
+    has = starts < ecap
+    vkeys = jnp.where(has, src_s[jnp.minimum(starts, ecap - 1)], INVALID_VID)
+    # Pad keys point at position n: empty slices, no masking needed.
+    voff = jnp.where(has, starts, n)
+    n = n.astype(jnp.int32)
+    return CSRRunArrays(
+        vkeys=vkeys.astype(jnp.int32),
+        voff=jnp.concatenate([voff, n[None]]).astype(jnp.int32),
+        dst=jnp.where(valid, dst_s, 0), ts=jnp.where(valid, ts_s, 0),
+        marker=jnp.where(valid, marker_s, False),
+        prop=jnp.where(valid, prop_s, 0.0),
+        nv=jnp.sum(has).astype(jnp.int32), ne=n,
+    )
+
+
+@jax.jit
+def _mask_invalid(src, n):
+    return jnp.where(jnp.arange(src.shape[0]) < n, src, INVALID_VID)
+
+
+@functools.partial(jax.jit, static_argnames=("vcap",))
+def _gather_build(src, dst, ts, marker, prop, n, order, *, vcap: int):
+    return build_sorted_run_arrays(src[order], dst[order], ts[order],
+                                   marker[order], prop[order], n, vcap=vcap)
+
+
 def build_run_arrays(
     src: jnp.ndarray,
     dst: jnp.ndarray,
@@ -35,28 +87,10 @@ def build_run_arrays(
     vcap: int,
 ) -> CSRRunArrays:
     """Sort raw edges into a CSR run. Entries at positions >= n are ignored."""
-    ecap = src.shape[0]
-    pos = jnp.arange(ecap, dtype=jnp.int32)
-    valid = pos < n
-    src = jnp.where(valid, src, INVALID_VID)
-    order = _lexsort_edges(src, dst, ts)
-    src_s = src[order]
-    dst_s = jnp.where(valid[order], dst[order], 0)
-    ts_s = jnp.where(valid[order], ts[order], 0)
-    marker_s = jnp.where(valid[order], marker[order], False)
-    prop_s = jnp.where(valid[order], prop[order], 0.0)
-
-    vkeys = jnp.unique(src_s, size=vcap, fill_value=INVALID_VID)
-    # Pads are INVALID_VID; searchsorted('left') lands them on the first pad
-    # edge position == n, yielding empty slices — no masking needed.
-    voff = jnp.searchsorted(src_s, vkeys, side="left").astype(jnp.int32)
-    voff_full = jnp.concatenate([voff, n[None].astype(jnp.int32)])
-    nv = jnp.sum(vkeys != INVALID_VID).astype(jnp.int32)
-    return CSRRunArrays(
-        vkeys=vkeys.astype(jnp.int32), voff=voff_full,
-        dst=dst_s, ts=ts_s, marker=marker_s, prop=prop_s,
-        nv=nv, ne=n.astype(jnp.int32),
-    )
+    n = jnp.asarray(n, jnp.int32)
+    src = _mask_invalid(src, n)
+    return _gather_build(src, dst, ts, marker, prop, n,
+                         _lexsort_edges(src, dst, ts), vcap=vcap)
 
 
 @jax.jit
@@ -71,17 +105,12 @@ def run_lookup(run: CSRRunArrays, v: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndar
     return found, jnp.where(found, start, 0), jnp.where(found, end, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("use_pallas",))
-def run_lookup_batch(run: CSRRunArrays, vs: jnp.ndarray,
-                     *, use_pallas: bool = False):
+@jax.jit
+def run_lookup_batch(run: CSRRunArrays, vs: jnp.ndarray):
     """Vectorized `run_lookup`: (found, start, end) for a whole int32 query
-    vector in one jit'd binary-search pass (optionally the Pallas batched
-    bisection kernel on TPU).  Pad slots (INVALID_VID) report not-found."""
-    if use_pallas:
-        from ..kernels import ops as kops  # picks interpret mode off-TPU
-        i = kops.batched_searchsorted(run.vkeys, vs, run.nv)
-    else:
-        i = jnp.searchsorted(run.vkeys, vs).astype(jnp.int32)
+    vector in one jit'd binary-search pass.  Pad slots (INVALID_VID) report
+    not-found."""
+    i = jnp.searchsorted(run.vkeys, vs).astype(jnp.int32)
     i_c = jnp.minimum(i, run.vcap - 1)
     found = (run.vkeys[i_c] == vs) & (vs != INVALID_VID)
     start = run.voff[i_c]
@@ -124,20 +153,21 @@ def run_gather(run: CSRRunArrays, start: jnp.ndarray, end: jnp.ndarray, *, cap: 
 
 def _gc_keep_mask(src: jnp.ndarray, dst: jnp.ndarray, ts: jnp.ndarray,
                   marker: jnp.ndarray, valid: jnp.ndarray,
-                  tau_min: jnp.ndarray, is_bottom: bool) -> jnp.ndarray:
+                  tau_min: jnp.ndarray,
+                  live_below: jnp.ndarray) -> jnp.ndarray:
     """Version-retention GC over (src,dst,ts)-sorted records (DESIGN.md §4).
 
     1. Drop a record iff a newer record of the same (src,dst) exists with
        ts <= tau_min (superseded before any live snapshot could see it).
-    2. PAIR ANNIHILATION: a newest-of-key tombstone (ts <= tau_min) is
-       dropped together with the insert it supersedes when the record
-       preceding that insert is absent or itself a delete — then nothing
-       deeper can be re-exposed (the key's deeper prefix necessarily ends
-       in a delete or never existed).  This keeps the multilevel ± analytics
-       invariant (Σ± per key == live count) exact across compactions for
-       alternating histories, while double-insert histories still retain
-       their tombstone for deep shadowing.
-    3. At the bottom level every dead newest-of-key tombstone drops.
+    2. A newest-of-key tombstone with ts <= tau_min (dead to every live
+       snapshot) drops unless ``live_below`` says the key's newest version
+       in the levels BELOW the merge output is an insert — then the
+       tombstone must stay to shadow it.  Where the key is dead or absent
+       below, nothing can be re-exposed, and the tombstone goes together
+       with the versions it superseded (for alternating insert/delete
+       histories the dropped records cancel, which keeps the multilevel ±
+       analytics invariant Σ± per key == live count).  At the bottom level
+       ``live_below`` is all False.
     """
     nxt_same = (
         valid
@@ -148,41 +178,73 @@ def _gc_keep_mask(src: jnp.ndarray, dst: jnp.ndarray, ts: jnp.ndarray,
     nxt_same = nxt_same.at[-1].set(False)
     nxt_ts = jnp.roll(ts, -1)
     superseded = nxt_same & (nxt_ts <= tau_min)
-    keep = valid & ~superseded
-
-    newest = ~nxt_same
-    # prev_same[i]: record i-1 has the same key as i.
-    prev_same = jnp.roll(nxt_same, 1).at[0].set(False)
-    prev_marker = jnp.roll(marker, 1).at[0].set(False)
-    # prev2_same[i]: record i-2 has the same key as i-1.
-    prev2_same = jnp.roll(nxt_same, 2).at[:2].set(False)
-    prev2_marker = jnp.roll(marker, 2).at[:2].set(False)
-    # The paired insert (i-1) is first-of-key or preceded by a delete.
-    pair_safe = prev_same & ~prev_marker & (~prev2_same | prev2_marker)
-    dead_tomb = (marker & newest & (ts <= tau_min)
-                 & (pair_safe if not is_bottom else True))
-    if not is_bottom:
-        keep = keep & ~dead_tomb
-    else:
-        keep = keep & ~(marker & newest & (ts <= tau_min))
-    return keep
+    dead_tomb = marker & ~nxt_same & (ts <= tau_min) & ~live_below
+    return valid & ~superseded & ~dead_tomb
 
 
-@functools.partial(jax.jit, static_argnames=("vcap", "is_bottom"))
-def _merge_impl(src, dst, ts, marker, prop, valid, tau_min, *, vcap: int,
-                is_bottom: bool) -> CSRRunArrays:
+def _sort_records(src, dst, ts, marker, prop, valid):
+    """(src, dst, ts)-sort a merge input; invalid slots sort last."""
     src = jnp.where(valid, src, INVALID_VID)
-    order = _lexsort_edges(src, dst, ts)
-    src, dst, ts = src[order], dst[order], ts[order]
-    marker, prop, valid = marker[order], prop[order], valid[order]
-    keep = _gc_keep_mask(src, dst, ts, marker, valid, tau_min, is_bottom)
-    src = jnp.where(keep, src, INVALID_VID)
+    return _gather(_lexsort_edges(src, dst, ts),
+                   src, dst, ts, marker, prop, valid)
+
+
+@jax.jit
+def _gather(order, *cols):
+    return tuple(c[order] for c in cols)
+
+
+@jax.jit
+def _newest_in_run(run: CSRRunArrays, src: jnp.ndarray, dst: jnp.ndarray):
+    """Per (src, dst) key: whether ``run`` holds it, and the ts and marker
+    of its newest record there.  A vertex's records in a merged run are
+    (dst, ts)-sorted, so the newest is the last one with dst <= key: one
+    vectorized upper-bound bisection inside each vertex's slice."""
+    found_v, start, end = run_lookup_batch(run, src)
+    last_i = run.ecap - 1
+
+    def step(_, lohi):
+        lo, hi = lohi
+        mid = (lo + hi) // 2
+        open_ = lo < hi
+        le = run.dst[jnp.minimum(mid, last_i)] <= dst
+        return (jnp.where(open_ & le, mid + 1, lo),
+                jnp.where(open_ & ~le, mid, hi))
+
+    n_steps = max(1, int(run.ecap).bit_length() + 1)
+    lo, _ = jax.lax.fori_loop(0, n_steps, step, (start, end))
+    last = jnp.clip(lo - 1, 0, last_i)
+    found = found_v & (lo > start) & (run.dst[last] == dst)
+    return found, run.ts[last], run.marker[last]
+
+
+def _live_below(deeper: Sequence[CSRRunArrays], src, dst) -> jnp.ndarray:
+    """Per (src, dst) key: its newest version across ``deeper`` is an
+    insert."""
+    best_ts = jnp.full(src.shape, jnp.iinfo(jnp.int32).min, jnp.int32)
+    live = jnp.zeros(src.shape, bool)
+    for run in deeper:
+        found, ts, marker = _newest_in_run(run, src, dst)
+        newer = found & (ts > best_ts)
+        best_ts = jnp.where(newer, ts, best_ts)
+        live = jnp.where(newer, ~marker, live)
+    return live
+
+
+@functools.partial(jax.jit, static_argnames=("vcap",))
+def _gc_build(src, dst, ts, marker, prop, valid, tau_min, live_below, *,
+              vcap: int) -> CSRRunArrays:
+    keep = _gc_keep_mask(src, dst, ts, marker, valid, tau_min, live_below)
+    return _compact_sorted(keep, src, dst, ts, marker, prop, vcap=vcap)
+
+
+def _compact_sorted(keep, src, dst, ts, marker, prop, *, vcap: int):
+    """The kept records of a sorted stream, moved in order to a dense
+    prefix (no sort), as a CSR run."""
     n = jnp.sum(keep).astype(jnp.int32)
-    # Stable compaction of survivors to a dense prefix.
-    order2 = jnp.argsort(~keep, stable=True)
-    src, dst, ts = src[order2], dst[order2], ts[order2]
-    marker, prop = marker[order2], prop[order2]
-    return build_run_arrays(src, dst, ts, marker, prop, n, vcap=vcap)
+    idx = jnp.nonzero(keep, size=keep.shape[0], fill_value=0)[0]
+    return build_sorted_run_arrays(src[idx], dst[idx], ts[idx], marker[idx],
+                                   prop[idx], n, vcap=vcap)
 
 
 def merge_runs(
@@ -190,12 +252,15 @@ def merge_runs(
     tau_min: int,
     *,
     vcap: int,
-    is_bottom: bool = False,
+    deeper: Sequence[CSRRunArrays] = (),
 ) -> CSRRunArrays:
     """Vertex-aware compaction merge of k runs into one (paper Example 1).
 
     The result keeps every version still visible to a snapshot >= tau_min and
-    annihilates superseded versions / dead tombstones.
+    annihilates superseded versions / dead tombstones.  ``deeper`` are the
+    runs below the output level whose vertex ranges overlap it (none at the
+    bottom): a dead tombstone survives only where one of them still holds
+    a live version of its key.
     """
     src = jnp.concatenate([_expand_src(r) for r in runs])
     dst = jnp.concatenate([r.dst for r in runs])
@@ -205,9 +270,11 @@ def merge_runs(
     valid = jnp.concatenate(
         [jnp.arange(r.ecap, dtype=jnp.int32) < r.ne for r in runs]
     )
-    return _merge_impl(src, dst, ts, marker, prop, valid,
-                       jnp.asarray(tau_min, jnp.int32),
-                       vcap=vcap, is_bottom=is_bottom)
+    src, dst, ts, marker, prop, valid = _sort_records(
+        src, dst, ts, marker, prop, valid)
+    live_below = _live_below(deeper, src, dst)
+    return _gc_build(src, dst, ts, marker, prop, valid,
+                     jnp.asarray(tau_min, jnp.int32), live_below, vcap=vcap)
 
 
 @jax.jit
@@ -221,18 +288,15 @@ def _expand_src(run: CSRRunArrays) -> jnp.ndarray:
     return jnp.where(e < run.ne, s, INVALID_VID)
 
 
+@functools.partial(jax.jit, static_argnames=("vcap",))
 def run_slice_vertex_range(run: CSRRunArrays, lo: int, hi: int,
                            *, vcap: int) -> CSRRunArrays:
     """Extract the sub-run covering vertices in [lo, hi).  Used by partial
     (per-segment) compaction to pull only the overlapping vertex range."""
     src = _expand_src(run)
     inside = (src >= lo) & (src < hi)
-    n = jnp.sum(inside).astype(jnp.int32)
-    order = jnp.argsort(~inside, stable=True)  # stable → keeps (src,dst,ts) order
-    return build_run_arrays(
-        src[order], run.dst[order], run.ts[order], run.marker[order],
-        run.prop[order], n, vcap=vcap,
-    )
+    return _compact_sorted(inside, src, run.dst, run.ts, run.marker,
+                           run.prop, vcap=vcap)
 
 
 def empty_run(vcap: int, ecap: int) -> CSRRunArrays:

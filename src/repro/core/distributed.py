@@ -24,7 +24,6 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..analytics.view import CSRView
@@ -148,11 +147,11 @@ def make_distributed_pagerank(mesh: Mesh, shard: ShardedCSR, *,
 
         return jax.lax.fori_loop(0, iters, body, x_local)
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         _one, mesh=mesh,
         in_specs=(spec_sharded,) * 5 + (spec_sharded,),
         out_specs=spec_sharded,
-        check_rep=False,
+        check_vma=False,
     )
 
     def run():
@@ -241,11 +240,11 @@ def make_route_updates(mesh: Mesh, *, v_local: int, n_shards: int,
             src, dst, prop, n_valid[0], v_local=v_local,
             n_shards=n_shards, bucket_cap=bucket_cap, axis=axis)
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         _route, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis)),
         out_specs=(P(axis), P(axis), P(axis), P(axis), P(axis)),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(mapped)
 
@@ -260,10 +259,10 @@ def make_route_edge_batches(mesh: Mesh, *, v_local: int, n_shards: int,
             src, dst, prop, marker, n_valid[0], v_local=v_local,
             n_shards=n_shards, bucket_cap=bucket_cap, axis=axis)
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         _route, mesh=mesh,
         in_specs=(P(axis),) * 5,
         out_specs=(P(axis),) * 6,
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(mapped)

@@ -65,36 +65,43 @@ def note_compaction(
     new_voff: jnp.ndarray,      # int32[Vc+1]
     new_nv: jnp.ndarray,
     new_fid: jnp.ndarray,
-    range_lo: jnp.ndarray,      # compacted source vertex range [lo, hi)
+    range_lo: jnp.ndarray,      # target vertex range [lo, hi) the output covers
     range_hi: jnp.ndarray,
+    src_lo: jnp.ndarray,        # compacted source vertex range [lo, hi)
+    src_hi: jnp.ndarray,        # (empty: no source level to clear)
     l0_min_fid_update: jnp.ndarray,  # max L0 fid involved + 1; -1 = not an L0 compaction
 ) -> IndexState:
     """Index maintenance after compaction into `level` (paper §4.2.2/§4.3).
 
-    1. Vertices in the source range lose their source-level entries:
+    1. Vertices in the SOURCE range lose their source-level entries:
        - L0 source: min-readable-fid := max involved fid + 1 and first-fid
          cleared (whole-L0 compactions, paper rule);
        - L_{level-1} source: its column cleared.
+       An output segment may reach past the source range (it absorbed a
+       wider overlapping target segment); source-level segments there were
+       not compacted and keep their entries.
     2. Vertices in the merged output gain (fid, offset) at `level`.
-    3. Vertices in range but absent from the output (fully annihilated) are
-       cleared at `level` too — handled by clearing the whole range first.
+    3. Vertices in the target range but absent from the output (fully
+       annihilated) are cleared at `level` too — handled by clearing the
+       whole range first.
     """
     vmax = idx.l0_first_fid.shape[0]
     allv = jnp.arange(vmax, dtype=jnp.int32)
     in_range = (allv >= range_lo) & (allv < range_hi)
+    in_src = (allv >= src_lo) & (allv < src_hi)
 
     l0_min = idx.l0_min_fid
     l0_first = idx.l0_first_fid
     is_l0 = l0_min_fid_update >= 0
-    l0_min = jnp.where(is_l0 & in_range,
+    l0_min = jnp.where(is_l0 & in_src,
                        jnp.maximum(l0_min, l0_min_fid_update), l0_min)
-    l0_first = jnp.where(is_l0 & in_range, INVALID_VID, l0_first)
+    l0_first = jnp.where(is_l0 & in_src, INVALID_VID, l0_first)
 
     lvl_fid, lvl_off = idx.lvl_fid, idx.lvl_off
     if level >= 2:
         src_col = level - 2
         lvl_fid = lvl_fid.at[:, src_col].set(
-            jnp.where(in_range, INVALID_VID, lvl_fid[:, src_col]))
+            jnp.where(in_src, INVALID_VID, lvl_fid[:, src_col]))
     tgt_col = level - 1
     # Clear the full range at the target, then write the surviving vertices.
     lvl_fid = lvl_fid.at[:, tgt_col].set(

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .csr import lexsort_order
 from .types import INVALID_VID, EdgeBatch, MemGraphState, StoreConfig
 
 _HASH_MULT = np.uint32(2654435761)
@@ -260,10 +261,12 @@ def flush_arrays(mg: MemGraphState):
     marker = jnp.concatenate([mg.seg_marker.reshape(-1), mg.ovf_marker])
     prop = jnp.concatenate([mg.seg_prop.reshape(-1), mg.ovf_prop])
     nvalid = jnp.sum(seg_valid, dtype=jnp.int32) + mg.ovf_n
-    # Compact valid entries to a dense prefix (stable keeps arrival order).
-    order = jnp.argsort(src == INVALID_VID, stable=True)
-    return (src[order], dst[order], ts[order], marker[order], prop[order],
-            nvalid)
+    # Compact valid entries to a dense prefix, in order (no sort).
+    order = jnp.nonzero(src != INVALID_VID, size=src.shape[0],
+                        fill_value=src.shape[0] - 1)[0]
+    pad = jnp.arange(src.shape[0]) >= nvalid
+    return (jnp.where(pad, INVALID_VID, src[order]), dst[order], ts[order],
+            marker[order], prop[order], nvalid)
 
 
 @functools.partial(jax.jit, static_argnames=("cap",))
@@ -337,17 +340,21 @@ def scan_vertices_batch(mg: MemGraphState, vs: jnp.ndarray):
     return qid, dst, ts, marker, prop
 
 
-@jax.jit
 def backbone_stream(mg: MemGraphState):
     """One MemGraph tier as a read-spine stream (rid = -1: always visible).
 
     The sealed-tier handoff: a tier frozen by the flush rotate enters the
     shared per-state read spine through this function, flattened and sorted
     into (src, dst, ts) order once.  Arrival-ordered, so this stream (alone)
-    pays a per-tier device lexsort; invalid slots already carry
-    src == INVALID_VID and sort to the tail."""
+    pays a per-tier record sort (``csr.lexsort_order``); invalid slots
+    already carry src == INVALID_VID and sort to the tail."""
     src, dst, ts, marker, prop, _n = flush_arrays(mg)
-    order = jnp.lexsort((ts, dst, src))
+    return _stream_in_order(lexsort_order(src, dst, ts),
+                            src, dst, ts, marker, prop)
+
+
+@jax.jit
+def _stream_in_order(order, src, dst, ts, marker, prop):
     rid = jnp.full(src.shape, -1, jnp.int32)
     return (src[order], dst[order], ts[order], rid,
             marker[order], prop[order])

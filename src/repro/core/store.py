@@ -338,7 +338,7 @@ def _build_state_backbone(state: StoreState, store: "LSMGraph"):
             cols = kops.tournament_merge([mem_stream, tuple(cols)])
             cols = _fit_spine_cols(cols, total)
     src, d, t, rid, m, p = cols
-    fwords, fmasks = _stack_presence(spine.runs)
+    fwords, fmasks = store._place(_stack_presence(spine.runs))
     return _ReadBackbone(src, d, t, rid, m, p, _np(d), _np(p),
                          list(spine.runs), fwords, fmasks)
 
@@ -390,9 +390,14 @@ class LSMGraph:
     reordered."""
 
     def __init__(self, cfg: StoreConfig, durability=None,
-                 obs_label: Optional[str] = None):
+                 obs_label: Optional[str] = None, device=None):
         cfg.validate()
         self.cfg = cfg
+        # Device that holds this store's arrays (None = JAX's default).
+        # The roots of the device state (MemGraph tiers, index, stacked
+        # filters) are committed to it; every run, spine and index array
+        # is computed from them and so stays on it.
+        self.device = device
         # Optional durability engine (repro.storage.DurableStorage): WAL /
         # segment-file / manifest hooks.  None = in-memory store (seed mode).
         self.durability = durability
@@ -447,16 +452,24 @@ class LSMGraph:
         self._spine_cache = _SpineCache()
         version = self.versions.publish((0,), (), 0)
         self._state = StoreState(
-            epoch=0, tau=0, mem=mg_mod.empty_memgraph(cfg), mem_id=0,
+            epoch=0, tau=0, mem=self._empty_memgraph(), mem_id=0,
             mem_full=None, mem_full_id=None,
             levels=tuple(() for _ in range(cfg.n_levels)),
-            index=mlindex.empty_index(cfg.vmax, cfg.n_levels),
+            index=self._place(mlindex.empty_index(cfg.vmax, cfg.n_levels)),
             runs_by_fid={}, version=version, degraded=(),
             spine=_SpineHandle())
         if durability is not None:
             durability.attach(self)
 
     # ------------------------------------------------------------------ util
+    def _place(self, tree):
+        """Commit a pytree of fresh arrays to the store's device."""
+        return tree if self.device is None else jax.device_put(
+            tree, self.device)
+
+    def _empty_memgraph(self) -> MemGraphState:
+        return self._place(mg_mod.empty_memgraph(self.cfg))
+
     @property
     def state(self) -> StoreState:
         """The current published state — one atomic reference read."""
@@ -717,7 +730,7 @@ class LSMGraph:
             if int(self._state.mem.ne) == 0:
                 return None
             with obs.REGISTRY.span("store_flush", store=self.obs_label):
-                fresh = mg_mod.empty_memgraph(self.cfg)  # device, pre-lock
+                fresh = self._empty_memgraph()  # device, pre-lock
                 deg = self.degraded_ranges()
                 with self._write_lock:
                     # _write_lock excludes in-flight appliers: self._ts is
@@ -897,9 +910,9 @@ class LSMGraph:
             r.nbytes for r in sources + overlap)
         tau_min = self.versions.min_live_tau(self._ts)
         vcap = csr.quantize_cap(max(tot_e, 1))
-        is_bottom = target_level == self.cfg.n_levels - 1
         merged = csr.merge_runs(all_runs, tau_min, vcap=vcap,
-                                is_bottom=is_bottom)
+                                deeper=self._runs_below(
+                                    target_level, sources + overlap))
         new_segs = self._resegment(merged, target_level)
         self.io.compaction_write += sum(r.nbytes for r in new_segs)
         obs.counter("store_level_write_bytes", store=self.obs_label,
@@ -936,6 +949,18 @@ class LSMGraph:
                     [removed[f] for f in sorted(removed)], new_segs,
                     target_level)
 
+    def _runs_below(self, level: int,
+                    inputs: List[RunFile]) -> List[csr.CSRRunArrays]:
+        """Runs in the levels below ``level`` whose vertex ranges overlap the
+        merge inputs': the only places an older version of a merged key
+        can live (levels are disjoint in vertex range within a level, and
+        every shallower record is newer)."""
+        lo = min(r.min_vid for r in inputs)
+        hi = max(r.max_vid for r in inputs)
+        return [r.ensure_loaded()
+                for lvl in self._state.levels[level + 1:] for r in lvl
+                if r.nv > 0 and r.min_vid <= hi and r.max_vid >= lo]
+
     def _commit_merge(self, *, sources, overlap, new_segs, merged_nv,
                       target_level, range_lo, range_hi, l0_max_fid,
                       also_remove) -> None:
@@ -962,50 +987,31 @@ class LSMGraph:
         # (fid, offset) per vertex, the cleared source level, and — for L0
         # compactions — the min readable L0 fid = max involved fid + 1.
         index = st.index
+        l0_update = jnp.asarray(
+            l0_max_fid + 1 if l0_max_fid is not None else -1, jnp.int32)
+        src_lo = jnp.asarray(range_lo, jnp.int32)
+        src_hi = jnp.asarray(range_hi, jnp.int32)
+
+        def note(index, vkeys, voff, nv, fid, lo, hi):
+            return mlindex.note_compaction(
+                index, level=target_level, new_vkeys=vkeys, new_voff=voff,
+                new_nv=nv, new_fid=jnp.asarray(fid, jnp.int32),
+                range_lo=jnp.asarray(lo, jnp.int32),
+                range_hi=jnp.asarray(hi, jnp.int32),
+                src_lo=src_lo, src_hi=src_hi, l0_min_fid_update=l0_update)
+
         for seg in new_segs:
-            index = mlindex.note_compaction(
-                index, level=target_level,
-                new_vkeys=seg.arrays.vkeys, new_voff=seg.arrays.voff,
-                new_nv=seg.arrays.nv, new_fid=jnp.asarray(seg.fid, jnp.int32),
-                range_lo=jnp.asarray(seg.min_vid, jnp.int32),
-                range_hi=jnp.asarray(seg.max_vid + 1, jnp.int32),
-                l0_min_fid_update=jnp.asarray(
-                    l0_max_fid + 1 if l0_max_fid is not None else -1,
-                    jnp.int32),
-            )
-        if not new_segs:
-            # Everything annihilated: still clear the range + L0 visibility.
-            index = mlindex.note_compaction(
-                index, level=target_level,
-                new_vkeys=jnp.full((1,), INVALID_VID, jnp.int32),
-                new_voff=jnp.zeros((2,), jnp.int32),
-                new_nv=jnp.asarray(0, jnp.int32),
-                new_fid=jnp.asarray(INVALID_VID, jnp.int32),
-                range_lo=jnp.asarray(range_lo, jnp.int32),
-                range_hi=jnp.asarray(range_hi, jnp.int32),
-                l0_min_fid_update=jnp.asarray(
-                    l0_max_fid + 1 if l0_max_fid is not None else -1,
-                    jnp.int32),
-            )
-        # Ranges between [range_lo, range_hi) not covered by new segs were
-        # annihilated; note_compaction's range-clear handled only per-seg
-        # ranges above, so clear the gaps explicitly.
-        if new_segs:
-            covered = [(s.min_vid, s.max_vid + 1) for s in new_segs]
-            gaps = _range_gaps(range_lo, range_hi, covered)
-            for (glo, ghi) in gaps:
-                index = mlindex.note_compaction(
-                    index, level=target_level,
-                    new_vkeys=jnp.full((1,), INVALID_VID, jnp.int32),
-                    new_voff=jnp.zeros((2,), jnp.int32),
-                    new_nv=jnp.asarray(0, jnp.int32),
-                    new_fid=jnp.asarray(INVALID_VID, jnp.int32),
-                    range_lo=jnp.asarray(glo, jnp.int32),
-                    range_hi=jnp.asarray(ghi, jnp.int32),
-                    l0_min_fid_update=jnp.asarray(
-                        l0_max_fid + 1 if l0_max_fid is not None else -1,
-                        jnp.int32),
-                )
+            a = seg.arrays
+            index = note(index, a.vkeys, a.voff, a.nv, seg.fid,
+                         seg.min_vid, seg.max_vid + 1)
+        # Target ranges between [range_lo, range_hi) not covered by a new
+        # seg were annihilated (all of it when there is no new seg): clear
+        # them at the target too.
+        covered = [(s.min_vid, s.max_vid + 1) for s in new_segs]
+        for (glo, ghi) in _range_gaps(range_lo, range_hi, covered):
+            index = note(index, jnp.full((1,), INVALID_VID, jnp.int32),
+                         jnp.zeros((2,), jnp.int32), jnp.asarray(0, jnp.int32),
+                         INVALID_VID, glo, ghi)
         self.io.index_write += merged_nv * 8
         new_runs = dict(st.runs_by_fid)
         for r in sources + overlap:
@@ -1054,7 +1060,8 @@ class LSMGraph:
                 voff=merged.voff[start_v:end_v + 1] - e_lo,
                 dst=merged.dst[e_lo:e_hi], ts=merged.ts[e_lo:e_hi],
                 marker=merged.marker[e_lo:e_hi], prop=merged.prop[e_lo:e_hi],
-                nv=jnp.asarray(n_v, jnp.int32), ne=jnp.asarray(n_e, jnp.int32))
+                nv=self._place(jnp.asarray(n_v, jnp.int32)),
+                ne=self._place(jnp.asarray(n_e, jnp.int32)))
             segs.append(self._wrap(csr.repad_run(sub, vcap, ecap), level=level))
             start_v = end_v
         return segs
@@ -1861,19 +1868,41 @@ def _run_backbone_stream(run: csr.CSRRunArrays, rid: jnp.ndarray):
             run.marker, run.prop)
 
 
+_I32MAX = np.iinfo(np.int32).max
+
+
 @jax.jit
+def _mem_qkey(qid, ts, tau, nq):
+    return jnp.where((qid < nq) & (ts <= tau), qid, _I32MAX)
+
+
 def _mem_resolve(qid, dst, ts, marker, prop, tau, nq):
     """Annihilate the ACTIVE MemGraph tier's records per (query, dst): one
-    lexsort by (qid, dst, ts); the newest τ-visible record of each pair
+    sort by (qid, dst, ts); the newest τ-visible record of each pair
     wins (a tombstone winner hides the pair).  Also emits the sorted
     (qid, dst) pair set holding ANY visible record — the suppression probe:
     by the ts tier-dominance invariant, every such pair's OVERALL winner
     lives in this tier, so the sealed spine's winner for it is discarded
     (`_suppress_sealed`).  Pair slots beyond ``n_present`` carry all-MAX
-    keys (sortedness preserved)."""
-    dead = jnp.iinfo(jnp.int32).max
-    qkey = jnp.where((qid < nq) & (ts <= tau), qid, dead)
-    order = jnp.lexsort((ts, dst, qkey))
+    keys (sortedness preserved).
+
+    The sort runs on the host over the queried records only (the scan
+    returns the whole overflow tier, mostly unqueried); see
+    ``csr.lexsort_order`` for why record sorts leave the device."""
+    qkey = _mem_qkey(qid, ts, tau, nq)
+    qk = np.asarray(qkey)
+    hit = np.flatnonzero(qk != _I32MAX)
+    sub = hit[np.lexsort((np.asarray(ts)[hit], np.asarray(dst)[hit],
+                          qk[hit]))]
+    order = np.concatenate([sub, np.flatnonzero(qk == _I32MAX)])
+    return _mem_resolve_sorted(
+        jax.device_put(order.astype(np.int32), qkey.sharding),
+        qkey, dst, marker, prop, nq)
+
+
+@jax.jit
+def _mem_resolve_sorted(order, qkey, dst, marker, prop, nq):
+    dead = _I32MAX
     q, d = qkey[order], dst[order]
     m, p = marker[order], prop[order]
     last = (q != jnp.roll(q, -1)) | (d != jnp.roll(d, -1))

@@ -29,6 +29,9 @@ BYTES_PER_EDGE = 16
 BYTES_PER_PROP = 4
 BYTES_PER_INDEX_ENTRY = 8
 
+# The paper's default MemGraph size (§6 setup): 64 MB of edge records.
+MEMGRAPH_BYTES = 64 << 20
+
 
 class EdgeBatch(NamedTuple):
     """A fixed-capacity batch of edge updates (insert or tombstone)."""
@@ -286,6 +289,25 @@ class StoreConfig:
     dedup_gc: bool = True          # drop superseded versions at compaction
     use_multilevel_index: bool = True   # Fig. 16 ablation switch
     memcache_mode: str = "memgraph"     # memgraph | array_only | skiplist_only
+
+    @classmethod
+    def for_graph(cls, n_vertices: int,
+                  mem_bytes: int = MEMGRAPH_BYTES) -> "StoreConfig":
+        """Size a store for a graph of ``n_vertices``: the MemGraph holds
+        ``mem_bytes`` of edge records (20 B each) before it flushes, and
+        its row pool, hash table and overflow tier are sized so that edge
+        count, not a secondary cap, triggers the flush.  Segment files at
+        L1+ split at one MemGraph's worth of edges."""
+        mem_edges = max(256, mem_bytes // (BYTES_PER_EDGE + BYTES_PER_PROP))
+        bc = min(1 << 12, mem_edges)
+        rows = min(n_vertices, mem_edges) + bc
+        # Power of two keeping `rows` under the 0.7 load factor at which
+        # memgraph_should_flush fires.
+        hash_slots = 1 << (-(-rows * 10 // 7) - 1).bit_length()
+        return cls(vmax=n_vertices, mem_edges=mem_edges, seg_size=8,
+                   n_segments=rows, hash_slots=hash_slots,
+                   ovf_cap=mem_edges + bc, batch_cap=bc,
+                   seg_target_edges=mem_edges)
 
     def level_capacity(self, level: int) -> int:
         """Edge capacity of level i: P * T**i (L0 counts runs, not edges)."""

@@ -1,12 +1,14 @@
-"""Pallas TPU kernels for LSMGraph's compute hot spots.
+"""Device kernels for LSMGraph's compute hot spots.
 
-Each kernel ships three artifacts (see EXAMPLE.md):
-  <name>.py — pl.pallas_call + BlockSpec VMEM tiling,
-  ops.py    — jit'd public wrapper (interpret=True on CPU),
-  ref.py    — pure-jnp oracle used by the allclose test sweeps.
+  <name>.py — the kernel (pl.pallas_call + BlockSpec VMEM tiling where
+              it is a Pallas kernel, jnp where it is not),
+  ops.py    — public wrappers; the one place each implementation is chosen,
+  ref.py    — pure-jnp oracles used by the allclose test sweeps.
 """
-from .ops import (attention, batched_searchsorted, default_interpret,
-                  gather_segmin, gather_segsum, lex_searchsorted, merge_perm)
+from .ops import (attention, gather_segmin, gather_segsum, interpret,
+                  lex_searchsorted, merge_streams, presence_matrix,
+                  tournament_merge)
 
-__all__ = ["attention", "batched_searchsorted", "default_interpret",
-           "gather_segmin", "gather_segsum", "lex_searchsorted", "merge_perm"]
+__all__ = ["attention", "gather_segmin", "gather_segsum", "interpret",
+           "lex_searchsorted", "merge_streams", "presence_matrix",
+           "tournament_merge"]

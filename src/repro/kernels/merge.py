@@ -1,45 +1,31 @@
-"""Pallas TPU kernel: merge-path sorted merges (read + compaction fast path).
+"""Device-side sorted merges (read spine + analytics collect).
 
-Compaction's k-way merge defaults to concat+bitonic-sort (csr.merge_runs) —
-the TPU-native choice for k > 2.  For the common 2-run case (partial
-compaction of one segment file into its overlap) this kernel implements the
-classical merge-path algorithm, O(n) work instead of O(n log n):
-
-  * jnp side: lexicographic binary search finds, for every output tile, the
-    diagonal split (a_start, b_start) — O(T log n) scalar work;
-  * kernel side: each program merges a bounded (BT + BT) window by
-    cross-ranking (broadcast compare + row-sum, VPU-shaped), then emits the
-    merge PERMUTATION via one-hot accumulation.  Payload application is a
-    single XLA gather outside.
+``merge_streams`` merges two whole (src, dst, ts)-sorted record streams,
+payload included, as a cross-rank merge: A[i]'s output position is i plus
+its lexicographic rank in B (a vectorized binary search), and the payload
+is applied by gathers only.  ``tournament_merge`` folds k pre-sorted
+sources with a log-k tournament of such pairwise passes, so k sources
+merge on device with no host lexsort — the deep-snapshot read path and the
+analytics collect both ride it.
 
 Keys are (k1, k2, k3) = (src, dst, ts) compared lexicographically — no 64-bit
 packing needed (TPUs have no native int64).
 
-On top of the two-way primitive sit ``merge_streams`` (one pairwise merge of
-whole record streams, payload included) and ``tournament_merge`` (a log-k
-tournament of pairwise passes): k pre-sorted sources merge on device with no
-host lexsort — the deep-snapshot read path and the analytics collect both
-ride it.  ``merge_streams`` has two backends: the Pallas merge-path kernel
-above, and a pure-jnp cross-rank merge (A[i]'s output position = i + its
-lexicographic rank in B; payload applied by gathers only, since XLA CPU
-scatters lower to a serial loop) — the fast path where Pallas would run in
-interpret mode.
+There is no Pallas merge-path kernel: its per-tile windows start at
+data-dependent element offsets and were read with an in-kernel vector
+gather, which Mosaic does not lower ("Only 2D gather is supported"), and
+it held both whole inputs in VMEM.  This jnp merge is the one
+implementation on every backend.
 """
 from __future__ import annotations
 
-import functools
 import threading
 from typing import Dict, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from repro import obs
-
-BT = 256  # output tile size
-_I32MAX = jnp.iinfo(jnp.int32).max
-
 
 class MergeStats:
     """Merge-path counters as a view over the metric registry.
@@ -152,118 +138,9 @@ def lex_searchsorted(keys_a, q1, q2, q3, n_keys, *, side: str):
     return state[0]
 
 
-def _merge_kernel(asplit_ref, bsplit_ref,
-                  a1_ref, a2_ref, a3_ref, b1_ref, b2_ref, b3_ref,
-                  na_ref, nb_ref, perm_ref):
-    t = pl.program_id(0)
-    a_s = asplit_ref[t]
-    b_s = bsplit_ref[t]
-    na = na_ref[0]
-    nb = nb_ref[0]
-    acap = a1_ref.shape[0]
-    idx = jnp.arange(BT, dtype=jnp.int32)
-
-    def win(ref, start, limit):
-        g = jnp.clip(start + idx, 0, ref.shape[0] - 1)
-        v = jnp.take(ref[...], g, axis=0)
-        return jnp.where(start + idx < limit, v, _I32MAX)
-
-    a1, a2, a3 = (win(r, a_s, na) for r in (a1_ref, a2_ref, a3_ref))
-    b1, b2, b3 = (win(r, b_s, nb) for r in (b1_ref, b2_ref, b3_ref))
-    a_valid = a_s + idx < na
-    b_valid = b_s + idx < nb
-
-    # Cross ranks: A[i] is preceded by #B strictly less; B[j] by #A <= (tie ->
-    # A first, i.e. stability).
-    b_lt_a = _lex_less(b1[None, :], b2[None, :], b3[None, :],
-                       a1[:, None], a2[:, None], a3[:, None], strict=True)
-    a_le_b = _lex_less(a1[None, :], a2[None, :], a3[None, :],
-                       b1[:, None], b2[:, None], b3[:, None], strict=False)
-    la = idx + jnp.sum(b_lt_a, axis=1, dtype=jnp.int32)   # local out pos of A[i]
-    lb = idx + jnp.sum(a_le_b, axis=1, dtype=jnp.int32)   # local out pos of B[j]
-    la = jnp.where(a_valid & (la < BT), la, BT)
-    lb = jnp.where(b_valid & (lb < BT), lb, BT)
-
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (BT, BT), 0)
-    contrib_a = jnp.sum(
-        jnp.where(lanes == la[None, :], (a_s + idx + 1)[None, :], 0), axis=1)
-    contrib_b = jnp.sum(
-        jnp.where(lanes == lb[None, :], (acap + b_s + idx + 1)[None, :], 0),
-        axis=1)
-    total = contrib_a + contrib_b       # 1-based to distinguish "no writer"
-    perm_ref[0, :] = jnp.where(total > 0, total - 1,
-                               acap + b1_ref.shape[0]).astype(jnp.int32)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def merge_perm(a_keys, b_keys, na, nb, *, interpret: bool = False):
-    """Permutation merging two lexicographically sorted key triples.
-
-    a_keys/b_keys: (k1, k2, k3) int32 arrays (fixed caps, valid prefixes
-    na/nb).  Returns perm int32[acap+bcap]: output position -> index into
-    concat(A, B); slots beyond na+nb point at acap+bcap.
-    """
-    a1, a2, a3 = a_keys
-    b1, b2, b3 = b_keys
-    acap, bcap = a1.shape[0], b1.shape[0]
-    cap = acap + bcap
-    n_tiles = (cap + BT - 1) // BT
-    na = jnp.asarray(na, jnp.int32)
-    nb = jnp.asarray(nb, jnp.int32)
-
-    # Merge-path splits: for output diagonal d = t*BT, find a_cnt in [0, BT]
-    # s.t. merging consumed a_cnt from A and d - a_cnt from B.  a_cnt is the
-    # count of A-elements whose output position < d, i.e. the standard
-    # "A[i] <= B[d-i-1]" diagonal search; equivalently a_cnt = number of a's
-    # among the first d outputs = d - (number of b's among first d outputs).
-    d = jnp.minimum(jnp.arange(n_tiles, dtype=jnp.int32) * BT, na + nb)
-    lo = jnp.maximum(0, d - nb)
-    hi = jnp.minimum(d, na)
-    steps = max(1, int(acap).bit_length() + 1)
-
-    def body(_, state):
-        lo, hi = state
-        mid = (lo + hi + 1) // 2       # candidate a_cnt
-        i = jnp.clip(mid - 1, 0, acap - 1)
-        j = jnp.clip(d - mid, 0, bcap - 1)
-        # consume A[mid-1] before B[d-mid] iff A[mid-1] <= B[d-mid]
-        a_ok = _lex_less(a1[i], a2[i], a3[i], b1[j], b2[j], b3[j],
-                         strict=False) | (d - mid >= nb)
-        ok = (mid <= 0) | a_ok
-        lo = jnp.where(ok, mid, lo)
-        hi = jnp.where(ok, hi, mid - 1)
-        return lo, hi
-
-    a_split, _ = jax.lax.fori_loop(0, steps, body, (lo, hi))
-    b_split = d - a_split
-
-    perm = pl.pallas_call(
-        _merge_kernel,
-        out_shape=jax.ShapeDtypeStruct((n_tiles, BT), jnp.int32),
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((n_tiles,), lambda i: (0,)),
-            pl.BlockSpec((n_tiles,), lambda i: (0,)),
-            pl.BlockSpec((acap,), lambda i: (0,)),
-            pl.BlockSpec((acap,), lambda i: (0,)),
-            pl.BlockSpec((acap,), lambda i: (0,)),
-            pl.BlockSpec((bcap,), lambda i: (0,)),
-            pl.BlockSpec((bcap,), lambda i: (0,)),
-            pl.BlockSpec((bcap,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((1, BT), lambda i: (i, 0)),
-        interpret=interpret,
-    )(a_split, b_split, a1, a2, a3, b1, b2, b3,
-      na[None], nb[None]).reshape(-1)[:cap]
-    return perm
-
-
-@functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
+@jax.jit
 def merge_streams(a_cols: Tuple[jnp.ndarray, ...],
-                  b_cols: Tuple[jnp.ndarray, ...], *,
-                  use_pallas: bool = False, interpret: bool = False):
+                  b_cols: Tuple[jnp.ndarray, ...]):
     """Merge two sorted record streams into one, payload included.
 
     ``a_cols``/``b_cols``: tuples whose first three columns are the int32
@@ -272,13 +149,6 @@ def merge_streams(a_cols: Tuple[jnp.ndarray, ...],
     key columns that sort to the tail (e.g. all INT32_MAX).  Returns the
     merged column tuple of length len(a) + len(b).
     """
-    if use_pallas:
-        na, nb = a_cols[0].shape[0], b_cols[0].shape[0]
-        perm = merge_perm(a_cols[:3], b_cols[:3],
-                          jnp.asarray(na, jnp.int32),
-                          jnp.asarray(nb, jnp.int32), interpret=interpret)
-        return tuple(jnp.concatenate([ca, cb])[perm]
-                     for ca, cb in zip(a_cols, b_cols))
     # Gather-only payload application (XLA CPU scatters lower to a serial
     # loop; gathers vectorize).  pos_a is strictly increasing, so for every
     # output slot o the count of A-elements among outputs [0, o] is
@@ -298,9 +168,8 @@ def merge_streams(a_cols: Tuple[jnp.ndarray, ...],
                  for cca, ccb in zip(a_cols, b_cols))
 
 
-def tournament_merge(streams: Sequence[Tuple[jnp.ndarray, ...]], *,
-                     use_pallas: bool = False, interpret: bool = False):
-    """log-k tournament of pairwise merge-path passes over k sorted streams.
+def tournament_merge(streams: Sequence[Tuple[jnp.ndarray, ...]]):
+    """log-k tournament of pairwise merges over k sorted streams.
 
     Adjacent streams pair per round; an odd straggler advances unmerged.
     Pairing is order-preserving and each pairwise pass is stable (A's ties
@@ -313,8 +182,7 @@ def tournament_merge(streams: Sequence[Tuple[jnp.ndarray, ...]], *,
     if not streams:
         raise ValueError("tournament_merge needs at least one stream")
     while len(streams) > 1:
-        nxt = [merge_streams(streams[i], streams[i + 1],
-                             use_pallas=use_pallas, interpret=interpret)
+        nxt = [merge_streams(streams[i], streams[i + 1])
                for i in range(0, len(streams) - 1, 2)]
         if len(streams) % 2:
             nxt.append(streams[-1])

@@ -1,104 +1,43 @@
-"""Public jit'd wrappers for the Pallas kernels.
+"""Public wrappers for the device kernels — the one place where each
+operation's implementation is chosen.
 
-On this CPU container the kernels execute in interpret mode (the kernel body
-runs as traced jnp on the host — bit-identical semantics to the TPU lowering
-contract); on a TPU backend they compile through Mosaic.  `PALLAS_INTERPRET`
-can force either mode.
+  * ``gather_segsum``/``gather_segmin``, ``merge_streams``/
+    ``tournament_merge`` and ``presence_matrix`` are jnp (XLA) on every
+    backend: each inner step is a data-dependent vector gather, which
+    Mosaic does not lower (see kernels/segment_reduce.py, kernels/merge.py,
+    kernels/presence.py).
+  * ``attention`` is opt-in Pallas (off the store's path).
+
+CPU and TPU run the same algorithm; the only difference is how a Pallas
+kernel executes (``interpret()``).
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
 
 from . import ref
 from .flash_attention import flash_attention as _flash
-from .lookup import batched_searchsorted as _search
-from .merge import (lex_searchsorted, merge_perm as _merge_perm,
-                    merge_streams as _merge_streams,
-                    tournament_merge as _tournament_merge)
-from .presence import (presence_matrix_pallas as _presence_pallas,
-                       presence_matrix_ref as _presence_ref)
+from .merge import lex_searchsorted, merge_streams, tournament_merge
+from .presence import presence_matrix
 from .segment_reduce import (gather_segmin as _gather_segmin,
                              gather_segsum as _gather_segsum)
 
 
-def default_interpret() -> bool:
-    env = os.environ.get("PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
+def interpret() -> bool:
+    """Pallas kernels run in interpret mode on the CPU backend (the test
+    suite) and compile through Mosaic everywhere else."""
+    return jax.default_backend() == "cpu"
 
 
-def gather_segsum(dst, seg_id, wt, x, *, n_out: int,
-                  use_pallas: bool = True) -> jnp.ndarray:
-    """Fused message gather + CSR segment sum (analytics inner loop)."""
-    if not use_pallas:
-        return ref.gather_segsum_ref(dst, seg_id, wt, x, n_out)
-    return _gather_segsum(dst, seg_id, wt, x, n_out=n_out,
-                          interpret=default_interpret())
+def gather_segsum(dst, seg_id, wt, x, *, n_out: int) -> jnp.ndarray:
+    """Message gather + CSR segment sum (analytics inner loop)."""
+    return _gather_segsum(dst, seg_id, wt, x, n_out=n_out)
 
 
-def gather_segmin(dst, seg_id, wt, x, *, n_out: int,
-                  use_pallas: bool = True) -> jnp.ndarray:
+def gather_segmin(dst, seg_id, wt, x, *, n_out: int) -> jnp.ndarray:
     """Segment-min relaxation (BFS / SSSP / CC inner loop)."""
-    if not use_pallas:
-        return ref.gather_segmin_ref(dst, seg_id, wt, x, n_out)
-    return _gather_segmin(dst, seg_id, wt, x, n_out=n_out,
-                          interpret=default_interpret())
-
-
-def merge_perm(a_keys, b_keys, na, nb, *, use_pallas: bool = True):
-    """Two-way sorted-merge permutation (compaction fast path)."""
-    if not use_pallas:
-        import numpy as np
-        return jnp.asarray(ref.merge_perm_ref(a_keys, b_keys, int(na),
-                                              int(nb)))
-    return _merge_perm(a_keys, b_keys, na, nb, interpret=default_interpret())
-
-
-def merge_streams(a_cols, b_cols, *, use_pallas=None):
-    """One pairwise sorted-stream merge, payload included (see
-    kernels/merge.py).  Backend default: the Pallas merge-path kernel on a
-    real TPU, the pure-jnp cross-rank gather merge where Pallas would only
-    run in interpret mode (CPU) — identical output either way."""
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    return _merge_streams(tuple(a_cols), tuple(b_cols),
-                          use_pallas=use_pallas,
-                          interpret=default_interpret())
-
-
-def tournament_merge(streams, *, use_pallas=None):
-    """log-k tournament of pairwise merges over k sorted record streams —
-    the k>2 generalization of ``merge_perm`` (ROADMAP "Kernel-merge k>2")."""
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    return _tournament_merge([tuple(s) for s in streams],
-                             use_pallas=use_pallas,
-                             interpret=default_interpret())
-
-
-def presence_matrix(words, masks, queries, *, use_pallas=None):
-    """Vectorized vertex-presence test: bool[R, B] hit matrix from every
-    visible run's packed filter words (the batched read path's pre-gate).
-    Backend default mirrors ``merge_streams``: the Pallas row-gather
-    kernel on a real TPU, the pure-jnp broadcast gather on CPU —
-    bit-identical either way."""
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    if not use_pallas:
-        return _presence_ref(words, masks, queries)
-    return _presence_pallas(words, masks, queries,
-                            interpret=default_interpret())
-
-
-def batched_searchsorted(keys, queries, n_keys, *, use_pallas: bool = True):
-    """Batched binary search (no-index ablation probe / L0 probes)."""
-    if not use_pallas:
-        return ref.searchsorted_ref(keys, queries, n_keys)
-    return _search(keys, queries, n_keys, interpret=default_interpret())
+    return _gather_segmin(dst, seg_id, wt, x, n_out=n_out)
 
 
 def attention(q, k, v, *, causal: bool = True, scale=None,
@@ -108,9 +47,9 @@ def attention(q, k, v, *, causal: bool = True, scale=None,
     if not use_pallas:
         return ref.mha_ref(q, k, v, causal=causal, scale=scale)
     return _flash(q, k, v, causal=causal, scale=scale,
-                  interpret=default_interpret())
+                  interpret=interpret())
 
 
-__all__ = ["gather_segsum", "gather_segmin", "merge_perm", "merge_streams",
-           "tournament_merge", "batched_searchsorted", "presence_matrix",
-           "attention", "lex_searchsorted", "default_interpret"]
+__all__ = ["gather_segsum", "gather_segmin", "merge_streams",
+           "tournament_merge", "presence_matrix",
+           "attention", "lex_searchsorted", "interpret"]

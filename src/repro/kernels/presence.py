@@ -1,7 +1,7 @@
-"""Pallas TPU kernel: vectorized vertex-presence test over run filters.
+"""Vectorized vertex-presence test over run filters (jnp, on device).
 
 One call answers "which of these B query vertices might each of these R
-runs contain?" as a dense int32[R, B] hit matrix — the batched read
+runs contain?" as a dense bool[R, B] hit matrix — the batched read
 path's pre-gate: rows of the per-(run, query) visibility matrix are
 ANDed with this before spine rank + index gather + annihilation, so
 filtered-out pairs never cost device work and fully-rejected cold runs
@@ -15,21 +15,17 @@ The hash is the same splitmix32 double-hash the host-side builder uses
 (``core.filters``) — formula-identical by contract, so a key inserted at
 build time can never miss at query time.
 
-Grid: (run, query-tile); each program holds one run's word row in VMEM
-and resolves BQ queries with FILTER_K gathers — the same row-resident
-gather shape as ``lookup.py``'s bisection kernel.
+The probe is FILTER_K gathers of filter words at hashed, data-dependent
+positions, and the filter rows need not fit VMEM, so there is no Pallas
+kernel: Mosaic does not lower an in-kernel vector gather ("Only 2D
+gather is supported").  XLA's gather is the one implementation.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from ..core.filters import FILTER_K, FILTER_SALT
-
-BQ = 256
 
 
 def _mix(x: jnp.ndarray) -> jnp.ndarray:
@@ -47,63 +43,18 @@ def _hash_pair(q: jnp.ndarray):
     return h1, h2
 
 
-def _probe(words, mask, h1, h2):
-    """AND of FILTER_K bit probes; ``words`` may be [W] or [R, W] (the
-    positions broadcast against its leading dims)."""
+@jax.jit
+def presence_matrix(words: jnp.ndarray, masks: jnp.ndarray,
+                    queries: jnp.ndarray) -> jnp.ndarray:
+    """bool[R, B] from uint32[R, W] words, uint32[R] masks (mbits - 1 per
+    run) and int32[B] queries: the AND of FILTER_K bit probes."""
+    h1, h2 = _hash_pair(queries.astype(jnp.uint32))
+    h1, h2, mask = h1[None, :], h2[None, :], masks[:, None]
     hit = None
     for i in range(FILTER_K):
         pos = (h1 + jnp.uint32(i) * h2) & mask
         w = (pos >> 5).astype(jnp.int32)
-        b = pos & jnp.uint32(31)
-        if words.ndim == 1:
-            bits = jnp.take(words, w, axis=0)
-        else:
-            bits = jnp.take_along_axis(words, w, axis=1)
-        h = ((bits >> b) & jnp.uint32(1)) != 0
+        bits = jnp.take_along_axis(words, w, axis=1)
+        h = ((bits >> (pos & jnp.uint32(31))) & jnp.uint32(1)) != 0
         hit = h if hit is None else (hit & h)
     return hit
-
-
-@jax.jit
-def presence_matrix_ref(words: jnp.ndarray, masks: jnp.ndarray,
-                        queries: jnp.ndarray) -> jnp.ndarray:
-    """Pure-jnp reference/fallback: bool[R, B] from uint32[R, W] words,
-    uint32[R] masks (mbits - 1 per run) and int32[B] queries."""
-    h1, h2 = _hash_pair(queries.astype(jnp.uint32))
-    return _probe(words, masks[:, None], h1[None, :], h2[None, :])
-
-
-def _kernel(q_ref, words_ref, mask_ref, out_ref):
-    q = q_ref[...]                       # int32[BQ]
-    words = words_ref[0, ...]            # uint32[W] — this run's row
-    mask = mask_ref[0]                   # uint32
-    h1, h2 = _hash_pair(q.astype(jnp.uint32))
-    out_ref[0, :] = _probe(words, mask, h1, h2).astype(jnp.int32)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def presence_matrix_pallas(words: jnp.ndarray, masks: jnp.ndarray,
-                           queries: jnp.ndarray, *,
-                           interpret: bool = False) -> jnp.ndarray:
-    """Pallas lowering of ``presence_matrix_ref`` (bit-identical)."""
-    r, w = words.shape
-    nq = queries.shape[0]
-    n_tiles = max(1, (nq + BQ - 1) // BQ)
-    qpad = n_tiles * BQ
-    if qpad != nq:
-        queries = jnp.concatenate(
-            [queries, jnp.zeros((qpad - nq,), jnp.int32)])
-    out = pl.pallas_call(
-        _kernel,
-        out_shape=jax.ShapeDtypeStruct((r, n_tiles * BQ), jnp.int32),
-        grid=(r, n_tiles),
-        in_specs=[
-            pl.BlockSpec((BQ,), lambda i, j: (j,)),
-            pl.BlockSpec((1, w), lambda i, j: (i, 0)),
-            pl.BlockSpec((1,), lambda i, j: (i,)),
-        ],
-        out_specs=pl.BlockSpec((1, BQ), lambda i, j: (i, j)),
-        interpret=interpret,
-    )(queries.astype(jnp.int32), words.astype(jnp.uint32),
-      masks.astype(jnp.uint32))
-    return out[:, :nq] != 0

@@ -47,14 +47,6 @@ def merge_perm_ref(a_keys, b_keys, na: int, nb: int) -> np.ndarray:
     return perm
 
 
-def searchsorted_ref(keys: jnp.ndarray, queries: jnp.ndarray,
-                     n_keys) -> jnp.ndarray:
-    """Left insertion points of queries into keys[:n_keys] (sorted)."""
-    k = jnp.where(jnp.arange(keys.shape[0]) < n_keys, keys,
-                  jnp.iinfo(jnp.int32).max)
-    return jnp.searchsorted(k, queries, side="left").astype(jnp.int32)
-
-
 def mha_ref(q, k, v, *, causal: bool = True, scale: float | None = None):
     """Reference attention: q [B,Hq,S,D], k/v [B,Hkv,S,D] (GQA broadcast)."""
     bq, hq, sq, d = q.shape

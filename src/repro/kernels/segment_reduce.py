@@ -1,18 +1,15 @@
-"""Pallas TPU kernel: fused gather + segment-sum over sorted CSR edges.
+"""Segment reduction over sorted CSR edges (jnp; XLA on every backend).
 
 The analytics hot loop (DESIGN.md §5): per edge e (sorted by source),
-   message = wt[e] * x[dst[e]]       (gather from a VMEM-resident vector)
-   y[seg_id[e]] += message           (segment reduction)
+   message = wt[e] * x[dst[e]]       (one XLA gather)
+   y[seg_id[e]] += message           (one sorted XLA segment reduction)
 
-TPU adaptation: the ragged per-vertex reduction is re-blocked into fixed
-edge tiles of BE edges.  Within a tile the (at most BE) distinct segments are
-compressed to local ranks in [0, BE), and the reduction becomes a dense
-one-hot matmul — an MXU-shaped (BE x BE) @ (BE,) contraction, the canonical
-TPU segment-sum trick.  A cheap XLA scatter-add combines per-tile partial
-windows (each tile covers a contiguous rank window because edges are sorted).
-
-VMEM budget per tile (BE=512, fp32): x (|V| <= 2^20 -> 4 MB) + 3*BE vectors +
-the BE x BE one-hot (1 MB) — comfortably inside 16 MB v5e VMEM.
+There is no Pallas kernel here.  Mosaic has no 1-D dynamic gather, so
+``x[dst]`` has to run in XLA anyway, and a tiled kernel that reduces the
+gathered messages only per tile still leaves an edge-sized sorted segment
+reduction to XLA.  XLA's ``segment_sum``/``segment_min`` with
+``indices_are_sorted=True`` is the one implementation; ``ref.py`` keeps an
+independent scatter form for the tests.
 """
 from __future__ import annotations
 
@@ -20,142 +17,30 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-BE = 512  # edge-tile size (MXU-aligned: 4 x 128)
-_INF = 3.0e38  # python float: jnp scalars may not be captured by kernels
+_INF = 3.0e38  # "unreachable" in BFS / SSSP / CC
 
 
-def _kernel(dst_ref, lrank_ref, wt_ref, x_ref, out_ref):
-    """One edge tile: partials[r] = sum_e 1[lrank==r] * wt[e] * x[dst[e]]."""
-    dst = dst_ref[...]          # int32[BE]
-    lrank = lrank_ref[...]      # int32[BE] in [0, BE)
-    wt = wt_ref[...]            # float32[BE] (0 for pads, -1 for tombstones)
-    x = x_ref[...]              # float32[V] — full vector in VMEM
-    vals = wt * jnp.take(x, dst, axis=0)
-    onehot = (lrank[None, :] == jax.lax.broadcasted_iota(
-        jnp.int32, (BE, BE), 0)).astype(jnp.float32)
-    out_ref[0, :] = jax.lax.dot_general(
-        onehot, vals[:, None], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)[:, 0]
-
-
-def _kernel_min(dst_ref, lrank_ref, wt_ref, x_ref, out_ref):
-    """Min variant (BFS/SSSP/CC relaxations): masked (BE, BE) min-reduce on
-    the VPU; wt here is an additive edge weight, pads carry +inf."""
-    dst = dst_ref[...]
-    lrank = lrank_ref[...]
-    wt = wt_ref[...]
-    x = x_ref[...]
-    vals = wt + jnp.take(x, dst, axis=0)
-    sel = lrank[None, :] == jax.lax.broadcasted_iota(
-        jnp.int32, (BE, BE), 0)
-    out_ref[0, :] = jnp.min(jnp.where(sel, vals[None, :], _INF), axis=1)
-
-
-@functools.partial(jax.jit, static_argnames=("n_out", "interpret"))
+@functools.partial(jax.jit, static_argnames=("n_out",))
 def gather_segsum(dst: jnp.ndarray, seg_id: jnp.ndarray, wt: jnp.ndarray,
-                  x: jnp.ndarray, *, n_out: int,
-                  interpret: bool = False) -> jnp.ndarray:
+                  x: jnp.ndarray, *, n_out: int) -> jnp.ndarray:
     """y[s] = Σ_{e: seg_id[e]==s} wt[e] * x[dst[e]].
 
-    seg_id must be non-decreasing (CSR order); pads carry wt == 0.
+    seg_id must be non-decreasing (CSR order); ids >= n_out are dropped.
     """
-    e = dst.shape[0]
-    n_tiles = max(1, (e + BE - 1) // BE)
-    epad = n_tiles * BE
-    if epad != e:
-        pad = epad - e
-        dst = jnp.concatenate([dst, jnp.zeros((pad,), jnp.int32)])
-        seg_id = jnp.concatenate(
-            [seg_id, jnp.full((pad,), seg_id[-1] if e else 0, jnp.int32)])
-        wt = jnp.concatenate([wt, jnp.zeros((pad,), wt.dtype)])
-
-    # Compress sorted seg ids to dense ranks; local rank within each tile is
-    # then guaranteed < BE (a tile holds at most BE distinct segments).
-    boundary = jnp.concatenate(
-        [jnp.ones((1,), jnp.int32),
-         (seg_id[1:] != seg_id[:-1]).astype(jnp.int32)])
-    rank = jnp.cumsum(boundary) - 1                      # int32[epad]
-    tile_base = rank[::BE]                               # int32[n_tiles]
-    lrank = (rank - jnp.repeat(tile_base, BE)).astype(jnp.int32)
-
-    partials = pl.pallas_call(
-        _kernel,
-        out_shape=jax.ShapeDtypeStruct((n_tiles, BE), jnp.float32),
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((BE,), lambda i: (i,)),
-            pl.BlockSpec((BE,), lambda i: (i,)),
-            pl.BlockSpec((BE,), lambda i: (i,)),
-            pl.BlockSpec((x.shape[0],), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((1, BE), lambda i: (i, 0)),
-        interpret=interpret,
-    )(dst.astype(jnp.int32), lrank, wt.astype(jnp.float32),
-      x.astype(jnp.float32))
-
-    # Combine: tile t's window starts at rank tile_base[t]; windows overlap
-    # only at tile boundaries.  One scatter-add in compressed-rank space,
-    # then map ranks back to segment ids.
-    ridx = tile_base[:, None] + jnp.arange(BE, dtype=jnp.int32)[None, :]
-    y_rank = jnp.zeros((epad,), jnp.float32).at[
-        jnp.clip(ridx, 0, epad - 1).reshape(-1)].add(partials.reshape(-1))
-    # Dead rank slots (> rank[-1]) received only zero partials, so mapping
-    # them to segment 0 is harmless.
-    seg_of_rank = jnp.zeros((epad,), jnp.int32).at[rank].max(seg_id)
-    y = jnp.zeros((n_out,), jnp.float32).at[
-        jnp.clip(seg_of_rank, 0, n_out - 1)].add(
-        jnp.where(seg_of_rank < n_out, y_rank, 0.0))
-    return y
+    vals = wt.astype(jnp.float32) * x.astype(jnp.float32)[dst]
+    return jax.ops.segment_sum(vals, seg_id, num_segments=n_out,
+                               indices_are_sorted=True)
 
 
-@functools.partial(jax.jit, static_argnames=("n_out", "interpret"))
+@functools.partial(jax.jit, static_argnames=("n_out",))
 def gather_segmin(dst: jnp.ndarray, seg_id: jnp.ndarray, wt: jnp.ndarray,
-                  x: jnp.ndarray, *, n_out: int,
-                  interpret: bool = False) -> jnp.ndarray:
-    """y[s] = min_{e: seg_id[e]==s} (wt[e] + x[dst[e]]); absent -> +inf.
+                  x: jnp.ndarray, *, n_out: int) -> jnp.ndarray:
+    """y[s] = min_{e: seg_id[e]==s} (wt[e] + x[dst[e]]); absent -> 3e38.
 
-    The relaxation primitive of BFS / SSSP / CC.  Pads carry wt = +inf.
+    The relaxation primitive of BFS / SSSP / CC.
     """
-    e = dst.shape[0]
-    n_tiles = max(1, (e + BE - 1) // BE)
-    epad = n_tiles * BE
-    if epad != e:
-        pad = epad - e
-        dst = jnp.concatenate([dst, jnp.zeros((pad,), jnp.int32)])
-        seg_id = jnp.concatenate(
-            [seg_id, jnp.full((pad,), seg_id[-1] if e else 0, jnp.int32)])
-        wt = jnp.concatenate([wt, jnp.full((pad,), _INF, wt.dtype)])
-
-    boundary = jnp.concatenate(
-        [jnp.ones((1,), jnp.int32),
-         (seg_id[1:] != seg_id[:-1]).astype(jnp.int32)])
-    rank = jnp.cumsum(boundary) - 1
-    tile_base = rank[::BE]
-    lrank = (rank - jnp.repeat(tile_base, BE)).astype(jnp.int32)
-
-    partials = pl.pallas_call(
-        _kernel_min,
-        out_shape=jax.ShapeDtypeStruct((n_tiles, BE), jnp.float32),
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((BE,), lambda i: (i,)),
-            pl.BlockSpec((BE,), lambda i: (i,)),
-            pl.BlockSpec((BE,), lambda i: (i,)),
-            pl.BlockSpec((x.shape[0],), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((1, BE), lambda i: (i, 0)),
-        interpret=interpret,
-    )(dst.astype(jnp.int32), lrank, wt.astype(jnp.float32),
-      x.astype(jnp.float32))
-
-    ridx = tile_base[:, None] + jnp.arange(BE, dtype=jnp.int32)[None, :]
-    y_rank = jnp.full((epad,), _INF, jnp.float32).at[
-        jnp.clip(ridx, 0, epad - 1).reshape(-1)].min(partials.reshape(-1))
-    seg_of_rank = jnp.zeros((epad,), jnp.int32).at[rank].max(seg_id)
-    live = jnp.arange(epad) <= rank[-1]
-    y = jnp.full((n_out,), _INF, jnp.float32).at[
-        jnp.clip(seg_of_rank, 0, n_out - 1)].min(
-        jnp.where(live & (seg_of_rank < n_out), y_rank, _INF))
-    return y
+    vals = wt.astype(jnp.float32) + x.astype(jnp.float32)[dst]
+    y = jax.ops.segment_min(vals, seg_id, num_segments=n_out,
+                            indices_are_sorted=True)
+    return jnp.minimum(y, _INF)

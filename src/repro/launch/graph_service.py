@@ -17,6 +17,7 @@ import numpy as np
 from .. import obs
 from ..analytics import (bfs, cc, materialize_csr, multilevel_pagerank,
                          multilevel_views, pagerank, scan_stats, sssp)
+from ..compile_cache import enable_compile_cache
 from ..core import StoreConfig
 from ..core.concurrent import ConcurrentLSMGraph
 from ..data.graphgen import powerlaw_edges, update_stream
@@ -84,6 +85,9 @@ def main() -> None:
                          "queries resolved in one neighbors_batch call "
                          "(0 disables)")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--memgraph-mb", type=float, default=64.0,
+                    help="MemGraph size in MB of edge records before a "
+                         "flush (the paper's default is 64)")
     ap.add_argument("--shards", type=int, default=0, metavar="N",
                     help="run the sharded service tier: N vertex-range "
                          "LSMGraph shards behind routed writes and "
@@ -127,11 +131,9 @@ def main() -> None:
     if args.trace:
         obs.REGISTRY.enable_tracing(capacity=65536)
 
+    enable_compile_cache()
     v = args.vertices
-    cfg = StoreConfig(vmax=v, mem_edges=1 << 12, seg_size=8,
-                      n_segments=1 << 12, hash_slots=1 << 13,
-                      ovf_cap=1 << 13, batch_cap=1 << 10,
-                      l0_run_limit=4, seg_target_edges=1 << 13)
+    cfg = StoreConfig.for_graph(v, int(args.memgraph_mb * (1 << 20)))
     if args.shards > 0:
         _run_sharded(args, cfg, report)
         _write_trace(args)
@@ -211,9 +213,10 @@ def _write_trace(args) -> None:
 
 
 # --------------------------------------------------------- shared phases
-def _ingest_stream(g, src, dst, flush):
+def _ingest_stream(g, src, dst, flush, progress=None):
     """Shared ingest loop (undirected doubling).  Returns (n_ops, last
-    write receipt/seq, seconds incl. the final flush)."""
+    write receipt/seq, seconds incl. the final flush).  ``progress``, if
+    given, is called with the records sent so far after each batch."""
     t0 = time.time()
     n_ops = 0
     last = None
@@ -223,6 +226,8 @@ def _ingest_stream(g, src, dst, flush):
         else:
             last = g.delete_edges(np.r_[s, d], np.r_[d, s])
         n_ops += 2 * len(s)
+        if progress is not None:
+            progress(n_ops)
     flush()
     return n_ops, last, time.time() - t0
 

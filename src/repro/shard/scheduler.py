@@ -41,6 +41,7 @@ from typing import Dict, List, Optional
 
 from .. import obs
 from ..obs.amplification import AmplificationLedger
+from ..storage.errors import StorageError
 
 
 class CompactionScheduler:
@@ -76,6 +77,7 @@ class CompactionScheduler:
         self._obs_interval.set(self.interval)
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
+        self._error: Optional[BaseException] = None
 
     # ------------------------------------------------------------- signals
     def _ack_window(self):
@@ -156,17 +158,28 @@ class CompactionScheduler:
         while not self._stop.wait(self.interval):
             try:
                 self.step()
-            except Exception:
-                # A mid-compaction shard fence/close must not kill the
-                # scheduler thread; the next tick re-reads health state.
-                pass
+            except StorageError:
+                # A shard fenced by a typed storage failure mid-compaction
+                # must not kill the scheduler; the next tick re-reads
+                # health state and skips it.
+                continue
+            except BaseException as e:  # handed to stop()
+                self._error = e
+                return
 
     def stop(self) -> None:
+        """Stop the thread; re-raise the failure that ended it, if any."""
         self._stop.set()
         t = self._thread
         if t is not None:
             t.join(timeout=30.0)
+            if t.is_alive():
+                raise RuntimeError("compaction scheduler thread did not stop "
+                                   "within 30 s")
             self._thread = None
+        err, self._error = self._error, None
+        if err is not None:
+            raise err
 
 
 __all__ = ["CompactionScheduler"]

@@ -363,7 +363,13 @@ class ShardedGraphStore:
 
     def __init__(self, cfg: Optional[StoreConfig] = None, n_shards: int = 1,
                  *, stores: Optional[Sequence[LSMGraph]] = None,
-                 max_workers: Optional[int] = None, scale_mem: bool = False):
+                 max_workers: Optional[int] = None, scale_mem: bool = False,
+                 devices: Optional[Sequence[object]] = None):
+        """``devices``: one JAX device per shard; shard i's device state
+        lives on ``devices[i]`` (None = every shard on the default
+        device)."""
+        if devices is not None and len(devices) != n_shards:
+            raise ValueError(f"{len(devices)} devices for {n_shards} shards")
         if stores is not None:
             self.shards = list(stores)
             n_shards = len(self.shards)
@@ -376,7 +382,10 @@ class ShardedGraphStore:
             # tiers to its 1/S slice (constant aggregate provisioning).
             shard_cfg = shard_scaled_config(cfg, n_shards) if scale_mem \
                 else cfg
-            self.shards = [LSMGraph(shard_cfg) for _ in range(n_shards)]
+            self.shards = [
+                LSMGraph(shard_cfg,
+                         device=None if devices is None else devices[i])
+                for i in range(n_shards)]
         self.cfg = cfg
         self.part = RangePartition.for_vmax(cfg.vmax, n_shards)
         # Coordinator epoch: writes apply to all owner shards under this

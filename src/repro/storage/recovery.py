@@ -152,6 +152,8 @@ def _recover_into(storage: DurableStorage, root: str, cfg: StoreConfig,
                 new_nv=rf.arrays.nv, new_fid=jnp.asarray(rf.fid, jnp.int32),
                 range_lo=jnp.asarray(rf.min_vid, jnp.int32),
                 range_hi=jnp.asarray(rf.max_vid + 1, jnp.int32),
+                src_lo=jnp.asarray(0, jnp.int32),
+                src_hi=jnp.asarray(0, jnp.int32),
                 l0_min_fid_update=jnp.asarray(-1, jnp.int32))
     # Resume τ at the DURABLE floor, not past it: every segment record has
     # ts < wal_floor (a flush persists exactly the records below its

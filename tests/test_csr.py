@@ -1,6 +1,7 @@
 """CSR run construction, lookup, merge + version-retention GC."""
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import csr
 from repro.core.types import INVALID_VID
@@ -58,25 +59,27 @@ def test_merge_vertex_aware_order():
 
 def test_merge_gc_pair_annihilation():
     # insert (1,2)@0 then tombstone (1,2)@5: with tau_min>=5 the PAIR
-    # annihilates at any level (the insert is first-of-key, so nothing
-    # deeper can be re-exposed — pair-annihilation rule, csr._gc_keep_mask).
+    # annihilates at any level where nothing below holds the key live
+    # (csr._gc_keep_mask) — here the level below holds only other keys.
     a = _mk([1], [2], ts=[0])
     b = _mk([1], [2], ts=[5], marker=[True])
-    m_mid = csr.merge_runs([a, b], tau_min=10, vcap=16, is_bottom=False)
+    below = _mk([1, 3], [1, 2], ts=[-2, -1])
+    m_mid = csr.merge_runs([a, b], tau_min=10, vcap=16, deeper=[below])
     assert int(m_mid.ne) == 0
-    m_bot = csr.merge_runs([a, b], tau_min=10, vcap=16, is_bottom=True)
+    m_bot = csr.merge_runs([a, b], tau_min=10, vcap=16)
     assert int(m_bot.ne) == 0
 
 
 def test_merge_gc_double_insert_keeps_tombstone():
-    # [ins@0, ins@1, del@5]: the del's partner ins@1 is preceded by a
-    # same-key INSERT -> pair-drop is unsafe above bottom (a deeper live
-    # generation may exist); the tombstone must survive to shadow it.
-    a = _mk([1, 1], [2, 2], ts=[0, 1])
+    # ins@-1 lives below; this merge holds [ins@1, del@5].  The del must
+    # survive above the bottom to shadow the deeper live generation, and
+    # goes once nothing below holds the key live.
+    a = _mk([1], [2], ts=[1])
     b = _mk([1], [2], ts=[5], marker=[True])
-    m_mid = csr.merge_runs([a, b], tau_min=10, vcap=16, is_bottom=False)
+    below = _mk([1], [2], ts=[-1])
+    m_mid = csr.merge_runs([a, b], tau_min=10, vcap=16, deeper=[below])
     assert int(m_mid.ne) == 1 and bool(np.asarray(m_mid.marker)[0])
-    m_bot = csr.merge_runs([a, b], tau_min=10, vcap=16, is_bottom=True)
+    m_bot = csr.merge_runs([a, b], tau_min=10, vcap=16)
     assert int(m_bot.ne) == 0
 
 
@@ -84,7 +87,8 @@ def test_merge_gc_orphan_tombstone_survives_mid_level():
     # A tombstone whose insert lives DEEPER (not in this merge) must survive
     # above the bottom level to shadow it.
     b = _mk([1], [2], ts=[5], marker=[True])
-    m_mid = csr.merge_runs([b], tau_min=10, vcap=16, is_bottom=False)
+    below = _mk([1, 1], [1, 2], ts=[0, 1])
+    m_mid = csr.merge_runs([b], tau_min=10, vcap=16, deeper=[below])
     assert int(m_mid.ne) == 1 and bool(np.asarray(m_mid.marker)[0])
 
 
@@ -92,8 +96,43 @@ def test_merge_gc_respects_live_snapshot():
     a = _mk([1], [2], ts=[0])
     b = _mk([1], [2], ts=[5], marker=[True])
     # A reader pinned at tau=3 must still see the original insert.
-    m = csr.merge_runs([a, b], tau_min=3, vcap=16, is_bottom=True)
+    m = csr.merge_runs([a, b], tau_min=3, vcap=16)
     assert int(m.ne) == 2
+
+
+@pytest.mark.parametrize("below, kept", [
+    ([([1], [2], [0], [False])], True),          # live insert below
+    ([([1], [2], [0], [False]),
+      ([1, 1], [2, 2], [1, 2], [False, True])], False),  # newest below: del
+    ([([1], [3], [0], [False])], False),         # other key below
+    ([], False),                                 # bottom
+])
+def test_merge_gc_tombstone_vs_newest_below(below, kept):
+    """[del@5, ins@6, del@7] merged above runs that may hold the key: the
+    dead newest tombstone stays exactly when the key's newest version
+    below is an insert (dropping it would resurrect the edge)."""
+    m = csr.merge_runs([_mk([1, 1, 1], [2, 2, 2], ts=[5, 6, 7],
+                            marker=[True, False, True])],
+                       tau_min=10, vcap=16,
+                       deeper=[_mk(s, d, ts=t, marker=mk)
+                               for s, d, t, mk in below])
+    assert int(m.ne) == int(kept)
+    if kept:
+        assert bool(np.asarray(m.marker)[0])
+
+
+def test_newest_in_run_bisects_within_vertex():
+    run = _mk([1, 1, 1, 2, 2, 4], [3, 5, 5, 3, 9, 5],
+              ts=[0, 1, 2, 3, 4, 5], marker=[False, False, True, False,
+                                              False, False])
+    src = jnp.asarray([1, 1, 1, 2, 2, 3, 4, 4, INVALID_VID], jnp.int32)
+    dst = jnp.asarray([3, 5, 4, 9, 5, 5, 5, 6, 5], jnp.int32)
+    found, ts, marker = csr._newest_in_run(run, src, dst)
+    found = np.asarray(found)
+    assert found.tolist() == [True, True, False, True, False, False, True,
+                              False, False]
+    assert np.asarray(ts)[found].tolist() == [0, 2, 4, 5]
+    assert np.asarray(marker)[found].tolist() == [False, True, False, False]
 
 
 def test_slice_vertex_range():

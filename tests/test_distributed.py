@@ -26,7 +26,7 @@ view = CSRView(voff=jnp.asarray(voff), dst=jnp.asarray(dst),
 mesh = jax.make_mesh((8,), ("data",))
 shard = partition_csr(view, 8)
 pr_d = make_distributed_pagerank(mesh, shard, iters=10)()
-pr_s = pagerank(view, iters=10, use_pallas=False)
+pr_s = pagerank(view, iters=10)
 maxdiff = float(jnp.abs(pr_d[:V] - pr_s).max())
 # compressed iterate exchanges (hillclimb C): accuracy vs fp32
 pr_bf16 = make_distributed_pagerank(mesh, shard, iters=10,

@@ -11,6 +11,7 @@ scheduler's rank / hot-skip / backoff policy.
 """
 import glob
 import os
+import time
 
 import numpy as np
 import pytest
@@ -24,7 +25,7 @@ from repro.shard.scheduler import CompactionScheduler
 from repro.shard.store import ShardedGraphStore
 from repro.storage import faultfs, open_store
 from repro.storage import segments as seg_mod
-from repro.storage.errors import CorruptionError
+from repro.storage.errors import CorruptionError, DurabilityLost
 
 
 def _durable_cfg(**kw):
@@ -64,7 +65,7 @@ def test_from_words_rejects_non_pow2():
 
 def test_host_device_probe_parity():
     """The numpy ``might_contain`` and the device ``presence_matrix``
-    (ref AND pallas-interpret) are the same formula by contract."""
+    are the same formula by contract."""
     rng = np.random.default_rng(11)
     runs = [rng.integers(0, 1 << 28, n).astype(np.int64)
             for n in (1, 40, 700)]
@@ -78,10 +79,8 @@ def test_host_device_probe_parity():
     queries = np.concatenate([runs[1][:20],
                               rng.integers(0, 1 << 28, 300)]).astype(np.int64)
     host = np.stack([f.might_contain(queries) for f in filts])
-    for use_pallas in (False, True):
-        dev = np.asarray(kops.presence_matrix(
-            mat, masks, queries, use_pallas=use_pallas))
-        np.testing.assert_array_equal(dev, host)
+    dev = np.asarray(kops.presence_matrix(mat, masks, queries))
+    np.testing.assert_array_equal(dev, host)
 
 
 def test_filter_property_no_false_negatives():
@@ -104,8 +103,7 @@ def test_filter_property_no_false_negatives():
         # Host and device probes agree on arbitrary queries.
         q = np.array(probes, np.int64)
         dev = np.asarray(kops.presence_matrix(
-            f.words[None, :], np.array([f.mbits - 1], np.uint32), q,
-            use_pallas=False))[0]
+            f.words[None, :], np.array([f.mbits - 1], np.uint32), q))[0]
         np.testing.assert_array_equal(dev, f.might_contain(q))
 
     inner()
@@ -369,4 +367,36 @@ def test_scheduler_backs_off_on_ack_latency_jump():
     out = sched.step()
     assert out["decision"] in ("compact", "idle")
     assert out["interval"] == pytest.approx(base)
+    g.close()
+
+
+@pytest.mark.parametrize("exc,swallowed", [
+    (DurabilityLost("fsync failed on a fenced shard"), True),
+    (RuntimeError("bug in a compaction"), False),
+])
+def test_scheduler_thread_surfaces_unexpected_errors(exc, swallowed):
+    """The background loop survives a typed storage failure (a shard
+    fenced mid-compaction) but hands any other error to ``stop()``."""
+    g = _sharded_with_debt()
+    sched = CompactionScheduler(g, interval=0.001)
+    ticks = []
+
+    def failing_step():
+        ticks.append(1)
+        raise exc
+
+    sched.step = failing_step
+    sched.start()
+    deadline = time.time() + 10.0
+    while len(ticks) < 3 and sched._thread.is_alive() \
+            and time.time() < deadline:
+        time.sleep(0.005)
+    if swallowed:
+        assert len(ticks) >= 3 and sched._thread.is_alive()
+        sched.stop()
+    else:
+        assert len(ticks) == 1
+        with pytest.raises(RuntimeError, match="bug in a compaction"):
+            sched.stop()
+    assert sched._thread is None
     g.close()

@@ -96,7 +96,7 @@ def test_multilevel_spmv_equals_materialized(ops):
     view = materialize_csr(snap, 64)
     deg_exact = np.asarray(view.degrees).astype(np.float32)
     deg_fast = np.asarray(multilevel_degree(
-        multilevel_views(snap), n_out=64, use_pallas=False))
+        multilevel_views(snap), n_out=64))
     np.testing.assert_allclose(deg_fast, deg_exact, atol=1e-4)
     snap.release()
 
@@ -120,6 +120,7 @@ def test_compact_index_matches_dense_semantics(entries):
 @given(st.integers(0, 100), st.integers(0, 100))
 @_sets
 def test_merge_perm_sizes(na, nb):
+    import jax.numpy as jnp
     from repro.kernels import ops as kops
     rng = np.random.default_rng(na * 101 + nb)
     cap = 128
@@ -129,15 +130,16 @@ def test_merge_perm_sizes(na, nb):
         k2 = rng.integers(0, 10, n).astype(np.int32)
         k3 = rng.integers(0, 100, n).astype(np.int32)
         o = np.lexsort((k3, k2, k1))
-        import jax.numpy as jnp
         out = []
         for k in (k1[o], k2[o], k3[o]):
-            p = np.zeros(cap, np.int32)
+            p = np.full(cap, np.iinfo(np.int32).max, np.int32)
             p[:n] = k
             out.append(jnp.asarray(p))
         return tuple(out)
 
-    perm = np.asarray(kops.merge_perm(mk(na), mk(nb), na, nb))
+    idx = jnp.arange(cap, dtype=jnp.int32)
+    merged = kops.merge_streams(mk(na) + (idx,), mk(nb) + (idx + cap,))
+    perm = np.asarray(merged[3])
     valid = perm[:na + nb]
     assert len(set(valid.tolist())) == na + nb  # a permutation
     assert ((valid < cap) | (valid >= cap)).all()

@@ -217,13 +217,12 @@ def test_run_lookup_batch_matches_scalar_run_lookup():
         jnp.zeros(500, bool), jnp.zeros(500, jnp.float32),
         jnp.asarray(500, jnp.int32), vcap=256)
     qs = jnp.asarray(np.arange(-0, 110), jnp.int32)
-    for use_pallas in (False, True):  # both the jnp and the kernel probe
-        f_b, s_b, e_b = (np.asarray(x) for x in csr_mod.run_lookup_batch(
-            run, qs, use_pallas=use_pallas))
-        for i, v in enumerate(np.asarray(qs)):
-            f, s, e = csr_mod.run_lookup(run, jnp.asarray(v, jnp.int32))
-            assert (bool(f), int(s), int(e)) == (bool(f_b[i]), int(s_b[i]),
-                                                 int(e_b[i])), (use_pallas, v)
+    f_b, s_b, e_b = (np.asarray(x) for x in csr_mod.run_lookup_batch(
+        run, qs))
+    for i, v in enumerate(np.asarray(qs)):
+        f, s, e = csr_mod.run_lookup(run, jnp.asarray(v, jnp.int32))
+        assert (bool(f), int(s), int(e)) == (bool(f_b[i]), int(s_b[i]),
+                                             int(e_b[i])), v
 
 
 def test_concurrent_writer_error_surfaces_on_next_call():

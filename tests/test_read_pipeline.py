@@ -193,12 +193,11 @@ def _rand_sorted_stream(rng, n, cap, key_lo=0, key_hi=40):
     return tuple(out), n
 
 
-def _check_tournament(streams, ns, use_pallas):
+def _check_tournament(streams, ns):
     import jax.numpy as jnp
     from repro.kernels import ops as kops
     merged = kops.tournament_merge(
-        [tuple(jnp.asarray(c) for c in s) for s in streams],
-        use_pallas=use_pallas)
+        [tuple(jnp.asarray(c) for c in s) for s in streams])
     total = sum(ns)
     cat = [np.concatenate([s[i][:n] for s, n in zip(streams, ns)])
            for i in range(5)]
@@ -206,13 +205,13 @@ def _check_tournament(streams, ns, use_pallas):
     for i in range(5):
         np.testing.assert_array_equal(
             np.asarray(merged[i])[:total], cat[i][order],
-            err_msg=f"col {i} (use_pallas={use_pallas})")
+            err_msg=f"col {i}")
 
 
 @pytest.mark.parametrize("k", list(range(3, 9)))
 def test_tournament_merge_matches_host_lexsort(k):
     """k = 3..8 pre-sorted sources: the log-k tournament is byte-identical
-    to a stable host lexsort of the concatenation — both backends."""
+    to a stable host lexsort of the concatenation."""
     rng = np.random.default_rng(100 + k)
     streams, ns = [], []
     for _ in range(k):
@@ -221,8 +220,7 @@ def test_tournament_merge_matches_host_lexsort(k):
         s, nn = _rand_sorted_stream(rng, n, cap)
         streams.append(s)
         ns.append(nn)
-    _check_tournament(streams, ns, use_pallas=False)
-    _check_tournament(streams, ns, use_pallas=True)
+    _check_tournament(streams, ns)
 
 
 def test_tournament_merge_hypothesis():
@@ -242,7 +240,7 @@ def test_tournament_merge_hypothesis():
             s, nn = _rand_sorted_stream(rng, n, cap, key_hi=6)  # many ties
             streams.append(s)
             ns.append(nn)
-        _check_tournament(streams, ns, use_pallas=False)
+        _check_tournament(streams, ns)
 
     inner()
 

@@ -93,3 +93,79 @@ def test_min_readable_fid_filters_l0(loaded):
     import numpy as np
     min_fid = np.asarray(g.index.l0_min_fid)
     assert (min_fid > 0).any()  # compactions bumped the readable floor
+
+
+def test_compaction_keeps_source_entries_outside_source_range():
+    """An output segment may reach past the compacted source range (it
+    absorbed a wider target segment); source-level entries of vertices
+    outside the source range belong to segments that were not compacted
+    and must survive."""
+    import jax.numpy as jnp
+    from repro.core import index as mlindex
+    from repro.core.types import INVALID_VID
+    idx = mlindex.empty_index(64, 3)
+    i32 = lambda x: jnp.asarray(x, jnp.int32)  # noqa: E731
+    # Two L1 segments: fid 7 holds vertices 10..19, fid 8 holds 30..39.
+    for fid, lo in ((7, 10), (8, 30)):
+        idx = mlindex.note_compaction(
+            idx, level=1, new_vkeys=i32(np.arange(lo, lo + 10)),
+            new_voff=i32(np.arange(11) * 2), new_nv=i32(10),
+            new_fid=i32(fid), range_lo=i32(lo), range_hi=i32(lo + 10),
+            src_lo=i32(0), src_hi=i32(0), l0_min_fid_update=i32(-1))
+    # fid 7 moves to L2, merged with an L2 segment spanning 0..49.
+    idx = mlindex.note_compaction(
+        idx, level=2, new_vkeys=i32(np.arange(0, 50)),
+        new_voff=i32(np.arange(51)), new_nv=i32(50), new_fid=i32(9),
+        range_lo=i32(0), range_hi=i32(50), src_lo=i32(10), src_hi=i32(20),
+        l0_min_fid_update=i32(-1))
+    l1 = np.asarray(idx.lvl_fid[:, 0])
+    assert (l1[10:20] == INVALID_VID).all()
+    assert (l1[30:40] == 8).all()
+    assert (np.asarray(idx.lvl_fid[:50, 1]) == 9).all()
+
+
+def _stream_model(ops, n):
+    """Newest update per (src, dst) wins; a delete hides the edge."""
+    keys = np.concatenate([s.astype(np.int64) * n + d for _, s, d in ops])
+    dead = np.concatenate([np.full(len(s), x) for x, s, _ in ops])
+    uniq, first = np.unique(keys[::-1], return_index=True)
+    live = uniq[~dead[::-1][first]]
+    return live // n, live % n
+
+
+@pytest.mark.parametrize("mode", ["concurrent", "reopen"])
+def test_rmat_update_stream_reads_match_model(mode, tmp_path):
+    """A duplicate-heavy R-MAT insert/delete stream through a tiny MemGraph
+    (many flushes, L0->L1->L2 compactions): every vertex's neighbor list
+    equals the newest-wins model, also with the active MemGraph holding
+    the last batch and after recovery from disk."""
+    from repro.core import StoreConfig
+    from repro.core.concurrent import ConcurrentLSMGraph
+    from repro.data.graphgen import rmat_edges, update_stream
+    from repro.storage import open_store
+    scale = 8
+    n = 1 << scale
+    cfg = StoreConfig.for_graph(n, mem_bytes=150 * 20)
+    src, dst = rmat_edges(scale, 16 << scale, seed=0)
+    if mode == "concurrent":
+        g = ConcurrentLSMGraph(store=open_store(str(tmp_path), cfg))
+    else:
+        g = open_store(str(tmp_path), cfg)
+    ops = []
+    for op, s, d in update_stream(src, dst):
+        s2, d2 = np.r_[s, d], np.r_[d, s]
+        ops.append((op == "delete", s2, d2))
+        (g.delete_edges if op == "delete" else g.insert_edges)(s2, d2)
+    if mode == "concurrent":
+        g.flush()
+    if mode == "reopen":
+        g.close()
+        g = open_store(str(tmp_path))
+    lsrc, ldst = _stream_model(ops, n)
+    voff = np.searchsorted(lsrc, np.arange(n + 1))
+    with g.snapshot() as snap:
+        got = snap.neighbors_batch(np.arange(n, dtype=np.int64))
+    bad = [v for v in range(n) if not np.array_equal(
+        np.asarray(got[v], np.int64), ldst[voff[v]:voff[v + 1]])]
+    g.close()
+    assert bad == []

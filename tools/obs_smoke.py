@@ -33,6 +33,9 @@ def fail(msg: str) -> None:
 def run_service(report_path: str, extra: list) -> None:
     cmd = [sys.executable, "-m", "repro.launch.graph_service",
            "--vertices", "300", "--edges", "2000", "--queries", "64",
+           # ~1k-edge MemGraph: the run must flush and compact to export
+           # the flush/compaction families.
+           "--memgraph-mb", "0.02",
            "--metrics", report_path] + extra
     env = dict(os.environ)
     env.setdefault("PYTHONPATH", "src")
