@@ -39,11 +39,14 @@ import numpy as np
 
 from . import csr, filters, index as mlindex, memgraph as mg_mod
 from .. import obs
+from ..compile_cache import count_programs_built
 from ..kernels import ops as kops
 from ..kernels.merge import MERGE_STATS as _MERGE_STATS
 from .types import (BYTES_PER_EDGE, BYTES_PER_PROP, INVALID_VID, EdgeBatch,
                     IOCounters, MemGraphState, RunFile, StoreConfig, Version)
 from .versions import VersionChain
+
+count_programs_built()
 
 
 def _np(x) -> np.ndarray:
@@ -411,10 +414,21 @@ class LSMGraph:
         # resolved once here so hot paths touch cached references only.
         self.obs_label = obs_label or f"s{next(_STORE_ORDINAL)}"
         self.io = IOCounters().bind(store=self.obs_label)
-        self._obs_apply = obs.histogram("store_apply_seconds",
-                                        store=self.obs_label)
-        self._obs_resolve = obs.histogram("read_resolve_seconds",
-                                          store=self.obs_label)
+        # Spans (see repro.obs, "Layer ownership").
+        label = self.obs_label
+        self._span_apply = obs.span_site("store_apply", store=label)
+        self._span_apply_wal = obs.span_site("store_apply_wal", store=label)
+        self._span_apply_insert = obs.span_site("store_apply_insert",
+                                                store=label)
+        self._span_apply_sync = obs.span_site("store_apply_sync",
+                                              store=label)
+        self._span_flush_check = obs.span_site("store_flush_check",
+                                               store=label)
+        self._span_flush_build = obs.span_site("store_flush_build",
+                                               store=label)
+        self._span_flush_index = obs.span_site("store_flush_index",
+                                               store=label)
+        self._span_resolve = obs.span_site("read_resolve", store=label)
         self._obs_publish = obs.counter("store_state_publish_total",
                                         store=self.obs_label)
         # NOTE: the L0-depth / runs-per-level GAUGES are deliberately not
@@ -617,8 +631,7 @@ class LSMGraph:
                         "background flush did not relieve a hard-full "
                         "MemGraph within 60 s")
             marker = np.full(n, delete, bool)
-            t_chunk = time.perf_counter()
-            with self._write_lock:
+            with self._span_apply(), self._write_lock:
                 st = self._state
                 with self._lock:
                     ts = np.arange(self._ts, self._ts + n, dtype=np.int32)
@@ -626,8 +639,9 @@ class LSMGraph:
                     if self.durability is not None:
                         # WAL-before-MemGraph: the batch is logged before it
                         # can become readable; fsync group-commits off-path.
-                        commit_seq = self.durability.on_apply(
-                            s, d, ts, marker, p)
+                        with self._span_apply_wal():
+                            commit_seq = self.durability.on_apply(
+                                s, d, ts, marker, p)
                 # Device-side insert OUTSIDE the commit lock: the functional
                 # MemGraph update builds the next tier off to the side
                 # (_write_lock keeps it single-writer) and only the
@@ -648,13 +662,11 @@ class LSMGraph:
                     # tau advances ONLY with a mem publish — every other
                     # commit keeps the tau of the content it carries.
                     self._swap_state(mem=new_mem, tau=self._ts)
-            self._obs_apply.observe(time.perf_counter() - t_chunk)
             # Amplification-ledger denominator: logical bytes the caller
             # handed us (20 B/edge record), counted once per accepted chunk.
             self._obs_ingest_bytes.inc(n * (BYTES_PER_EDGE + BYTES_PER_PROP))
             (self._obs_edges_del if delete else self._obs_edges_ins).inc(n)
-            if allow_flush and mg_mod.memgraph_should_flush(
-                    self._state.mem, self.cfg):
+            if allow_flush and self._should_flush():
                 self.flush_memgraph()
         return commit_seq
 
@@ -663,19 +675,31 @@ class LSMGraph:
         the given MemGraph tier, returning ``(new_mem, ok)``.  Functional:
         the caller publishes the returned tier.  Runs under ``_write_lock``
         (single writer), never under the commit lock.  Shared by the live
-        write path (store-assigned ts) and WAL replay (original ts)."""
+        write path (store-assigned ts) and WAL replay (original ts), which
+        both time its two halves: the padding, put and dispatch
+        (``store_apply_insert``) and the wait for ``ok``
+        (``store_apply_sync``)."""
         bc = self.cfg.batch_cap
-        batch = EdgeBatch(
-            src=jnp.asarray(_pad(s, bc)),
-            dst=jnp.asarray(_pad(d, bc)),
-            ts=jnp.asarray(_pad(t, bc)),
-            prop=jnp.asarray(_pad(p, bc)),
-            marker=jnp.asarray(_pad(m, bc)),
-            n=jnp.asarray(len(s), jnp.int32),
-        )
-        new_mem, ok = mg_mod.insert_batch(
-            mem, batch, mode=self.cfg.memcache_mode)
-        return new_mem, bool(ok)
+        with self._span_apply_insert():
+            batch = EdgeBatch(
+                src=jnp.asarray(_pad(s, bc)),
+                dst=jnp.asarray(_pad(d, bc)),
+                ts=jnp.asarray(_pad(t, bc)),
+                prop=jnp.asarray(_pad(p, bc)),
+                marker=jnp.asarray(_pad(m, bc)),
+                n=jnp.asarray(len(s), jnp.int32),
+            )
+            new_mem, ok = mg_mod.insert_batch(
+                mem, batch, mode=self.cfg.memcache_mode)
+        with self._span_apply_sync():
+            ok = bool(ok)
+        return new_mem, ok
+
+    def _should_flush(self) -> bool:
+        """The flush trigger on the published MemGraph (its device scalars
+        read back to the host), timed as ``store_flush_check``."""
+        with self._span_flush_check():
+            return mg_mod.memgraph_should_flush(self._state.mem, self.cfg)
 
     def _ingest_replay(self, src, dst, ts, marker, prop) -> None:
         """Recovery-only ingest: re-insert WAL records with their ORIGINAL
@@ -705,7 +729,7 @@ class LSMGraph:
             self._obs_ingest_bytes.inc(n * (BYTES_PER_EDGE + BYTES_PER_PROP))
             self._obs_edges_del.inc(nd)
             self._obs_edges_ins.inc(n - nd)
-            if mg_mod.memgraph_should_flush(self._state.mem, self.cfg):
+            if self._should_flush():
                 self.flush_memgraph()
 
     def _mem_hard_full(self) -> bool:
@@ -758,18 +782,21 @@ class LSMGraph:
                         self.durability.on_flush_rotate(wal_floor)
                 obs.REGISTRY.trace_instant("store_flush_rotate",
                                            store=self.obs_label)
-                src, dst, ts, marker, prop, n = mg_mod.flush_arrays(mem_full)
-                cap = csr.quantize_cap(int(n))
-                run = csr.build_run_arrays(src, dst, ts, marker, prop, n,
-                                           vcap=cap)
-                run = csr.repad_run(run, cap, cap)
-                rf = self._wrap(run, level=0)
+                with self._span_flush_build():
+                    src, dst, ts, marker, prop, n = mg_mod.flush_arrays(
+                        mem_full)
+                    cap = csr.quantize_cap(int(n))
+                    run = csr.build_run_arrays(src, dst, ts, marker, prop,
+                                               n, vcap=cap)
+                    run = csr.repad_run(run, cap, cap)
+                    rf = self._wrap(run, level=0)
                 # Index update off-lock: _flush_lock (held) is the only
                 # serializer of index mutation; apply publishes never touch
                 # it.
-                new_index = mlindex.note_l0_flush(
-                    self._state.index, run.vkeys, run.nv,
-                    jnp.asarray(rf.fid, jnp.int32))
+                with self._span_flush_index():
+                    new_index = mlindex.note_l0_flush(
+                        self._state.index, run.vkeys, run.nv,
+                        jnp.asarray(rf.fid, jnp.int32))
                 self.io.flush_write += rf.nbytes
                 self.io.index_write += int(run.nv) * 8
                 # Per-level write-amp numerator (logical movement; durable
@@ -1441,19 +1468,9 @@ class Snapshot:
         """Timed wrapper over ``_resolve_batch_impl``: every device resolve
         (one per <= _BATCH_CHUNK query chunk) lands in the owning store's
         ``read_resolve_seconds`` histogram."""
-        t0 = time.perf_counter()
-        out = self._resolve_batch_impl(u, pad_to)
-        dt = time.perf_counter() - t0
-        self._store._obs_resolve.observe(dt)
+        with self._store._span_resolve():
+            out = self._resolve_batch_impl(u, pad_to)
         self._store._obs_read_queries.inc(len(u))
-        ring = obs.REGISTRY.trace_ring  # one check; None = tracing off
-        if ring is not None:
-            ring.append({"name": "read_resolve",
-                         "labels": {"store": self._store.obs_label,
-                                    "queries": str(len(u))},
-                         "t0": t0, "dur": dt, "depth": 0,
-                         "thread": threading.current_thread().name,
-                         "ok": True})
         return out
 
     def _resolve_batch_impl(self, u: np.ndarray,

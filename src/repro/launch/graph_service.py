@@ -9,9 +9,12 @@ analytics (PageRank / BFS / SSSP) run against consistent snapshots.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import time
+from typing import Optional
 
+import jax
 import numpy as np
 
 from .. import obs
@@ -111,12 +114,14 @@ def main() -> None:
                          "family) after each phase; FILE = rewrite a JSON "
                          "report there, bare flag = print to stdout at the "
                          "end")
-    ap.add_argument("--trace", default=None, metavar="FILE",
-                    help="record the span trace ring for the whole run and "
-                         "write it as Chrome trace-event / Perfetto JSON "
-                         "to FILE at exit (open at ui.perfetto.dev): "
-                         "flush/compaction/resolve spans plus lifecycle "
-                         "instants (rotate, commit, quarantine, fence)")
+    ap.add_argument("--trace", default=None, metavar="DIR",
+                    help="run the whole service under the JAX profiler and "
+                         "write its trace under DIR (one .xplane.pb; open "
+                         "it in Perfetto or TensorBoard): the store's "
+                         "spans (apply, WAL, flush, compaction, resolve), "
+                         "its lifecycle instants (rotate, commit, "
+                         "quarantine, fence) and the device's operations, "
+                         "on one clock")
     ap.add_argument("--chaos", action="store_true",
                     help="fault-injection phase (needs --shards and "
                          "--durable): corrupt one shard's newest segment "
@@ -128,15 +133,33 @@ def main() -> None:
     if args.chaos and not (args.shards > 0 and args.durable):
         ap.error("--chaos requires --shards N and --durable DIR")
     report = _MetricsReport(args.metrics) if args.metrics else _NullReport()
-    if args.trace:
-        obs.REGISTRY.enable_tracing(capacity=65536)
-
     enable_compile_cache()
+    with profiled(args.trace):
+        _run(args, report)
+
+
+@contextlib.contextmanager
+def profiled(trace_dir: Optional[str]):
+    """Run the enclosed code under the JAX profiler, its trace written
+    under ``trace_dir`` when the code ends; nothing when ``trace_dir`` is
+    None."""
+    if not trace_dir:
+        yield
+        return
+    jax.profiler.start_trace(trace_dir)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+        print(f"trace: written under {trace_dir} (.xplane.pb; open in "
+              "Perfetto or TensorBoard)")
+
+
+def _run(args, report) -> None:
     v = args.vertices
     cfg = StoreConfig.for_graph(v, int(args.memgraph_mb * (1 << 20)))
     if args.shards > 0:
         _run_sharded(args, cfg, report)
-        _write_trace(args)
         return
     if args.durable:
         from ..storage import open_store
@@ -201,15 +224,6 @@ def main() -> None:
         snap.release()
         g.close()
     report.finish()
-    _write_trace(args)
-
-
-def _write_trace(args) -> None:
-    if not args.trace:
-        return
-    n = obs.export_chrome_trace(args.trace, obs.REGISTRY)
-    print(f"trace: {n} events written to {args.trace} "
-          "(Chrome trace-event JSON; open at ui.perfetto.dev)")
 
 
 # --------------------------------------------------------- shared phases

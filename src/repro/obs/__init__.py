@@ -2,9 +2,9 @@
 
 One process-wide ``MetricRegistry`` (``REGISTRY``) holds every counter,
 gauge, and latency histogram in the system; ``span(...)`` times scopes
-into duration histograms and, when tracing is enabled, a bounded
-in-memory trace ring.  ``export_json``/``export_prometheus`` snapshot the
-whole registry; ``Reporter`` does so periodically.  The compaction
+into duration histograms and, while a JAX profiler session is active,
+onto the profiler's trace.  ``export_json``/``export_prometheus``
+snapshot the whole registry; ``Reporter`` does so periodically.  The compaction
 scheduler, the adaptive LSM tuner, and the serving front end (ROADMAP)
 all read from here rather than growing their own ad-hoc state.
 
@@ -24,15 +24,29 @@ layer's instruments:
 
 * ``store_*``  — core/store.py + core/concurrent.py: apply/flush/
   compaction spans, ``store_state_publish_total``, ``store_l0_depth`` and
-  ``store_level_runs`` gauges, background-thread error counts.
-* ``storage_*`` — storage/wal.py + storage/engine.py: WAL append/fsync
-  latency, group-commit batch size, segment write/load/evict, scrubber
-  verdicts, quarantine counts.
+  ``store_level_runs`` gauges, background-thread error counts.  The
+  write path's spans, per chunk of at most ``batch_cap`` records:
+  ``store_apply`` (the chunk), inside it ``store_apply_wal`` (WAL record
+  encode + append under the commit lock), ``store_apply_insert``
+  (padding, host-to-device put, dispatch of the MemGraph insert) and
+  ``store_apply_sync`` (the wait for the insert's ok flag); after it
+  ``store_flush_check`` (the flush trigger's device scalar reads).
+  Inside ``store_flush``: ``store_flush_build`` (flush arrays, CSR run
+  build and repad, the run file's host metadata, with any compiles they
+  trigger) and ``store_flush_index`` (the multi-level index update).
+* ``storage_*`` — storage/wal.py + storage/engine.py: the spans
+  ``storage_wal_append``, ``storage_wal_fsync``, ``storage_wal_ack``
+  (the whole ``sync_upto``: how long an acking client blocks, including
+  behind an in-flight group commit), ``storage_flush_persist`` (a
+  flush's segment write, manifest edit and WAL prune) and
+  ``storage_segment_write``; group-commit batch size, segment
+  load/evict, scrubber verdicts, quarantine counts.
 * ``shard_*``  — shard/store.py: per-shard fencing state, ack latency,
   degraded-range count, routed-batch fan-out.
 * ``read_*``   — the read path (core/store.py resolve + core/types.py
-  prefetch): resolve batch latency, prefetch hit/miss, and the presence-
-  filter counters — ``read_filter_checked_total`` ((run, query) pairs
+  prefetch): the ``read_resolve`` span (one per batched resolve),
+  prefetch hit/miss, and the presence-filter counters —
+  ``read_filter_checked_total`` ((run, query) pairs
   tested against a run's vertex-presence filter),
   ``read_filter_skipped_total`` (pairs the filter proved absent — device
   work and, on the per-run paths, cold segment loads avoided),
@@ -50,6 +64,10 @@ layer's instruments:
   kept byte-compatible with the legacy dataclass API.
 * ``merge_*``  — the ``MERGE_STATS`` view (kernels/merge.py): kernel-vs-
   host merge branch counts, spine build/splice/reuse.
+* ``runtime_*`` — compile_cache.py: ``runtime_programs_built_total``,
+  every program this process compiled or loaded from the persistent
+  compilation cache (one per ``jax.monitoring`` backend-compile event,
+  which a cache load raises too).  Installed when the store is imported.
 * ``amp_*``    — derived amplification gauges (obs/amplification.py):
   written ONLY by ``AmplificationLedger.refresh_gauges`` — never by a
   hot path.
@@ -75,14 +93,17 @@ full compaction, a ratio losing its denominator) is removed via
 ``MetricRegistry.remove`` at the owning commit point, so exporters stop
 reporting it; stale last values never outlive their subject.
 
-**Trace export.**  With tracing enabled (``REGISTRY.enable_tracing``),
-spans land in the bounded ring together with point lifecycle events
-(``trace_instant``: flush rotate/commit, compaction commit, WAL rotate,
-quarantine, rebuild, shard fence).  ``obs/trace_export.py`` converts the
-ring to Chrome trace-event / Perfetto JSON (spans → ``ph:"X"`` duration
-events per thread, instants → ``ph:"i"`` markers, families → ``cat``,
-failed spans carry ``args.ok: false``); ``graph_service --trace FILE``
-writes it at exit.
+**Trace export.**  There is one trace: the JAX profiler's.  While a
+profiler session is active (``jax.profiler.start_trace``, or
+``graph_service --trace DIR``), every span opens a profiler TraceMe
+named by the span (labels ride as its metadata) and every
+``trace_instant`` (flush rotate/commit, compaction commit, WAL rotate,
+quarantine, rebuild, shard fence) is a zero-length one.  They land in
+the session's ``.xplane.pb`` on the ``/host:CPU`` plane, one line per
+thread, nested with JAX's own dispatch, transfer and compile spans and
+on the same clock as the device's operations; Perfetto or TensorBoard
+opens it.  A span that triggers a compile shows the compile nested
+under it.
 
 **Label cardinality.**  Labels multiply series; every label must be
 bounded by configuration, never by data.  Allowed: store ordinal
@@ -92,13 +113,16 @@ numbers, file ids, timestamps — anything that grows with the workload
 belongs in a histogram observation or a trace event, not a label.
 
 **Cost.**  Instruments are cached at call sites (module- or
-instance-level attributes), so hot paths pay one lock + one add — never
-a registry map lookup.  The span hot path pays two ``perf_counter``
-calls and one histogram observe; the trace ring adds exactly one
-attribute check while disabled.  ``tests/test_obs.py`` enforces the
-per-op bound and the < 2% ingest overhead budget.
+instance-level attributes; spans as a ``SpanSite``), so hot paths pay
+one lock + one add — never a registry map lookup.  With no profiler
+session a span pays two ``perf_counter`` calls, one histogram observe
+and one ``TraceMe.is_enabled()`` check (about 0.1 us); a TraceMe is
+built only inside a session.  This package imports nothing of JAX: it
+finds the TraceMe class once JAX has loaded it.  ``tests/test_obs.py``
+enforces the per-op bound and the < 2% ingest overhead budget.
 """
-from .registry import (Counter, Gauge, Histogram, MetricRegistry, Span)
+from .registry import (Counter, Gauge, Histogram, MetricRegistry, Span,
+                       SpanSite)
 from .export import SCHEMA, Reporter, export_json, export_prometheus
 
 #: The process-wide default registry every production call site uses.
@@ -108,8 +132,6 @@ REGISTRY = MetricRegistry()
 # its definition.
 from .amplification import (AMP_SCHEMA, AmplificationLedger,  # noqa: E402
                             shard_amplification)
-from .trace_export import (export_chrome_trace,               # noqa: E402
-                           to_chrome_trace)
 
 
 def counter(name: str, **labels) -> Counter:
@@ -128,10 +150,14 @@ def span(name: str, **labels) -> Span:
     return REGISTRY.span(name, **labels)
 
 
+def span_site(name: str, **labels) -> SpanSite:
+    return REGISTRY.span_site(name, **labels)
+
+
 __all__ = [
     "REGISTRY", "SCHEMA", "AMP_SCHEMA", "MetricRegistry", "Counter",
-    "Gauge", "Histogram", "Span", "Reporter", "AmplificationLedger",
-    "export_json", "export_prometheus", "export_chrome_trace",
-    "to_chrome_trace", "shard_amplification",
-    "counter", "gauge", "histogram", "span",
+    "Gauge", "Histogram", "Span", "SpanSite", "Reporter",
+    "AmplificationLedger", "export_json", "export_prometheus",
+    "shard_amplification", "counter", "gauge", "histogram", "span",
+    "span_site",
 ]

@@ -16,17 +16,18 @@ Cost model (the "near-zero when nothing is attached" contract):
 
   * ``Counter.inc`` / ``Gauge.set``: one lock + one add (~0.2 us);
   * ``Histogram.observe``: one ``math.log`` + one lock + array bump;
-  * ``span(...)``: two ``perf_counter`` calls + one histogram observe; the
-    trace ring costs ONE attribute check (``registry.trace_ring is None``)
-    when tracing is disabled — events are built only while a ring is
-    attached.  ``tests/test_obs.py`` enforces the per-op bound.
+  * a span: two ``perf_counter`` calls + one histogram observe + ONE
+    profiler check (``TraceMe.is_enabled()``, about 0.1 us) while no JAX
+    profiler session is active; a TraceMe is built only inside a session.
+    Hot paths call a cached ``SpanSite`` (no registry map lookup);
+    ``tests/test_obs.py`` enforces the per-op bound.
 """
 from __future__ import annotations
 
 import math
+import sys
 import threading
 import time
-from collections import deque
 from typing import Dict, List, Optional, Tuple
 
 LabelKey = Tuple[Tuple[str, str], ...]
@@ -214,19 +215,40 @@ class Histogram:
         return summary
 
 
+# The profiler's TraceMe class once JAX has loaded it (see _traceme).
+_TRACEME = None
+
+
+def _traceme():
+    """The profiler's TraceMe class, or None where no session can be open.
+
+    Resolved lazily and cached once found: a profiler session exists only
+    in a process that has loaded JAX, so until ``jaxlib._profiler`` is
+    imported this is one dict lookup, and this module never imports JAX
+    itself (nor touches a device)."""
+    global _TRACEME
+    tm = _TRACEME
+    if tm is None:
+        mod = sys.modules.get("jaxlib._profiler")
+        if mod is not None:
+            tm = _TRACEME = mod.TraceMe
+    return tm
+
+
 class Span:
     """Timed scope: ``with registry.span("store_flush", store="s0"): ...``
-    records the duration into the ``<name>_seconds`` histogram and — only
-    while a trace ring is attached — appends a trace event carrying name,
-    labels, thread, nesting depth, wall window, and outcome.
+    records the duration into the ``<name>_seconds`` histogram and, only
+    while a JAX profiler session is active, opens a profiler TraceMe named
+    ``name`` (labels as its metadata), so the span lands on the trace's
+    host plane, on its thread's line, nested with every other host span
+    and on the same clock as the device's operations.
 
-    A span that exits via an exception records ``ok: False`` on its trace
-    event and bumps ``<name>_errors_total`` (same labels), so failed
-    flushes/compactions are visible in both traces and counters; the
-    exception itself always propagates."""
+    A span that exits via an exception sets ``ok`` False and bumps
+    ``<name>_errors_total`` (same labels), so failed flushes/compactions
+    are visible in the counters; the exception itself always propagates."""
 
     __slots__ = ("_reg", "_hist", "name", "labels", "t0", "duration",
-                 "_depth", "ok")
+                 "ok", "_tm")
 
     def __init__(self, reg: "MetricRegistry", hist: Histogram, name: str,
                  labels: Dict[str, str]):
@@ -236,14 +258,14 @@ class Span:
         self.labels = labels
         self.t0 = 0.0
         self.duration = 0.0
-        self._depth = 0
         self.ok = True
+        self._tm = None
 
     def __enter__(self) -> "Span":
-        if self._reg.trace_ring is not None:  # the one hot-path check
-            tls = self._reg._tls
-            self._depth = getattr(tls, "depth", 0)
-            tls.depth = self._depth + 1
+        tm = _traceme()
+        if tm is not None and tm.is_enabled():  # the one hot-path check
+            self._tm = tm(self.name, **self.labels)
+            self._tm.__enter__()
         self.t0 = time.perf_counter()
         return self
 
@@ -251,22 +273,34 @@ class Span:
         dt = time.perf_counter() - self.t0
         self.duration = dt
         self._hist.observe(dt)
+        if self._tm is not None:
+            self._tm.__exit__(exc_type, exc, tb)
+            self._tm = None
         if exc_type is not None:
             # Error path only: the registry map lookup is fine here — a
             # failing span is never the hot path.
             self.ok = False
             self._reg.counter(self.name + "_errors_total",
                               **self.labels).inc()
-        ring = self._reg.trace_ring
-        if ring is not None:
-            tls = self._reg._tls
-            tls.depth = max(getattr(tls, "depth", 1) - 1, 0)
-            ring.append({
-                "name": self.name, "labels": dict(self.labels),
-                "t0": self.t0, "dur": dt, "depth": self._depth,
-                "thread": threading.current_thread().name,
-                "ok": exc_type is None,
-            })
+
+
+class SpanSite:
+    """One span's name and labels bound once to their histogram: a call
+    site caches this and calls it for a fresh ``Span`` per scope, so the
+    hot path never touches the registry map
+    (``with self._span_apply(): ...``)."""
+
+    __slots__ = ("_reg", "_hist", "name", "labels")
+
+    def __init__(self, reg: "MetricRegistry", name: str,
+                 labels: Dict[str, str]):
+        self._reg = reg
+        self._hist = reg.histogram(name + "_seconds", **labels)
+        self.name = name
+        self.labels = {k: str(v) for k, v in labels.items()}
+
+    def __call__(self) -> Span:
+        return Span(self._reg, self._hist, self.name, self.labels)
 
 
 class MetricRegistry:
@@ -280,10 +314,6 @@ class MetricRegistry:
     def __init__(self) -> None:
         self._mu = threading.Lock()
         self._metrics: Dict[Tuple[str, LabelKey], object] = {}
-        # Bounded in-memory trace ring; None = tracing disabled (the span
-        # hot path checks exactly this attribute).
-        self.trace_ring: Optional[deque] = None
-        self._tls = threading.local()
 
     def _get_or_create(self, cls, name: str, labels: dict, **kw):
         key = (name, _label_key(labels))
@@ -312,8 +342,12 @@ class MetricRegistry:
                                    buckets_per_decade=buckets_per_decade)
 
     def span(self, name: str, **labels) -> Span:
-        hist = self.histogram(name + "_seconds", **labels)
-        return Span(self, hist, name, labels)
+        """A one-off span (one registry lookup); hot paths cache a
+        ``span_site`` instead."""
+        return self.span_site(name, **labels)()
+
+    def span_site(self, name: str, **labels) -> SpanSite:
+        return SpanSite(self, name, labels)
 
     def remove(self, name: str, **labels) -> bool:
         """Drop one series (exact name + labels) from the registry so
@@ -341,31 +375,14 @@ class MetricRegistry:
 
     # ------------------------------------------------------------ tracing
     def trace_instant(self, name: str, **labels) -> None:
-        """Record a zero-duration lifecycle event (flush rotate/commit,
-        compaction commit, quarantine, fence...) into the trace ring.
-        Exactly one attribute check when tracing is disabled — safe to
-        leave on cold paths unconditionally."""
-        ring = self.trace_ring
-        if ring is None:
-            return
-        ring.append({
-            "name": name, "labels": {k: str(v) for k, v in labels.items()},
-            "t0": time.perf_counter(), "dur": None,
-            "depth": getattr(self._tls, "depth", 0),
-            "thread": threading.current_thread().name, "ok": True,
-        })
-
-    def enable_tracing(self, capacity: int = 4096) -> None:
-        """Attach a bounded trace ring; spans start recording events."""
-        self.trace_ring = deque(maxlen=capacity)
-
-    def disable_tracing(self) -> None:
-        self.trace_ring = None
-
-    def trace_events(self) -> List[dict]:
-        """Copy of the ring (oldest first); empty when tracing is off."""
-        ring = self.trace_ring
-        return list(ring) if ring is not None else []
+        """A zero-length lifecycle event (flush rotate/commit, compaction
+        commit, quarantine, fence...) on the profiler's trace while a
+        session is active; one enabled check otherwise — safe to leave on
+        cold paths unconditionally."""
+        tm = _traceme()
+        if tm is not None and tm.is_enabled():
+            with tm(name, **{k: str(v) for k, v in labels.items()}):
+                pass
 
     # ------------------------------------------------------------- export
     def collect(self) -> List[object]:

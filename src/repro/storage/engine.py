@@ -35,7 +35,6 @@ import dataclasses
 import fcntl
 import os
 import threading
-import time
 from typing import Dict, List, Optional, Set, Tuple
 
 from .. import obs
@@ -51,7 +50,8 @@ SEGMENT_DIR = "segments"
 WAL_DIR = "wal"
 QUARANTINE_DIR = scrub_mod.QUARANTINE_DIR
 
-_OBS_SEG_WRITE = obs.histogram("storage_segment_write_seconds")
+_SPAN_SEG_WRITE = obs.span_site("storage_segment_write")
+_SPAN_FLUSH_PERSIST = obs.span_site("storage_flush_persist")
 _OBS_EVICT = obs.counter("storage_segment_evict_total")
 _OBS_QUARANTINE = obs.counter("storage_quarantine_total")
 
@@ -138,9 +138,8 @@ class DurableStorage:
         return nbytes
 
     def _write_segment_timed(self, path: str, rf: RunFile) -> int:
-        t0 = time.perf_counter()
-        nbytes = seg_mod.write_segment(path, rf)
-        _OBS_SEG_WRITE.observe(time.perf_counter() - t0)
+        with _SPAN_SEG_WRITE():
+            nbytes = seg_mod.write_segment(path, rf)
         # Physical per-level write-amp numerator: every segment-file write
         # (flush, compaction output, scrub heal) funnels through here.
         if self.store is not None:
@@ -265,20 +264,22 @@ class DurableStorage:
         self._pending_wal_seq = self.wal.rotate() - 1
 
     def on_flush_commit(self, rf: RunFile, wal_floor: int) -> None:
-        """The L0 run is built and published in memory: make it durable."""
-        path = self.seg_path(rf.fid)
-        nbytes = self._write_segment_timed(path, rf)
-        desc = self._segdesc(rf, wal_seq=self._pending_wal_seq)
-        rf.path = path
-        rf.loader = self.make_loader(path, desc)
-        self.seg_descs[rf.fid] = desc
-        self.store.io.segment_write += nbytes
-        self._crashpoint("pre_manifest_flush")
-        self._manifest_append({
-            "op": "flush", "tau": wal_floor, "wal_floor": wal_floor,
-            "next_fid": self.store._next_fid, "add": [desc],
-        })
-        self.wal.prune(wal_floor, retain=self.wal_retain)
+        """The L0 run is built and published in memory: make it durable
+        (segment write, manifest edit, WAL prune: ``storage_flush_persist``)."""
+        with _SPAN_FLUSH_PERSIST():
+            path = self.seg_path(rf.fid)
+            nbytes = self._write_segment_timed(path, rf)
+            desc = self._segdesc(rf, wal_seq=self._pending_wal_seq)
+            rf.path = path
+            rf.loader = self.make_loader(path, desc)
+            self.seg_descs[rf.fid] = desc
+            self.store.io.segment_write += nbytes
+            self._crashpoint("pre_manifest_flush")
+            self._manifest_append({
+                "op": "flush", "tau": wal_floor, "wal_floor": wal_floor,
+                "next_fid": self.store._next_fid, "add": [desc],
+            })
+            self.wal.prune(wal_floor, retain=self.wal_retain)
 
     def on_compact_segments(self, new_segs: List[RunFile]) -> None:
         """Write the merge outputs (lock-free compute phase).  Orphaned on

@@ -19,7 +19,6 @@ from __future__ import annotations
 import os
 import struct
 import threading
-import time
 import zlib
 from typing import Dict, Iterator, List, NamedTuple, Optional, Tuple
 
@@ -32,8 +31,9 @@ from .fsutil import fsync_dir
 
 # WAL instruments (module-level: WALs are per-store but short-lived across
 # reopens; per-instance labels would grow unbounded over restarts).
-_OBS_APPEND = obs.histogram("storage_wal_append_seconds")
-_OBS_FSYNC = obs.histogram("storage_wal_fsync_seconds")
+_SPAN_APPEND = obs.span_site("storage_wal_append")
+_SPAN_FSYNC = obs.span_site("storage_wal_fsync")
+_SPAN_ACK = obs.span_site("storage_wal_ack")
 # Group-commit batch size: appends covered per fsync (lo=1: a size, not a
 # latency).
 _OBS_BATCH = obs.REGISTRY.histogram(
@@ -204,8 +204,7 @@ class WriteAheadLog:
         """Shared framed-append core: seq allocation, fail-stop check, and
         the per-policy fsync — one implementation for every record type so
         the commit-seq / fsyncgate protocol cannot desynchronize."""
-        t0 = time.perf_counter()
-        with self._io_lock:
+        with _SPAN_APPEND(), self._io_lock:
             self._check_failed()
             try:
                 faultfs.write(self._fd, rec, self._path)
@@ -227,7 +226,6 @@ class WriteAheadLog:
                 _OBS_BATCH.observe(1)
             elif self.sync_mode == "batch":
                 self._dirty.set()
-        _OBS_APPEND.observe(time.perf_counter() - t0)
         return WalAppend(seq, len(rec))
 
     def append_edges(self, src, dst, ts, marker, prop) -> WalAppend:
@@ -265,21 +263,21 @@ class WriteAheadLog:
                 upto = self._appended_seq  # every seq <= upto is in the file
                 batch = upto - self._durable_seq  # appends this commit covers
                 self._dirty.clear()
-            t0 = time.perf_counter()
-            try:
-                faultfs.fsync(fd, path)
-            except OSError:
-                # fsyncgate: the kernel may mark pages clean after a FAILED
-                # fsync, so retrying cannot restore durability.  Latch a
-                # sticky fail-stop — every later append/sync raises instead
-                # of silently claiming durability that was never achieved.
-                with self._io_lock:
-                    self._sync_failed = True
-                    self._dirty.set()
-                raise
-            finally:
-                os.close(fd)
-            _OBS_FSYNC.observe(time.perf_counter() - t0)
+            with _SPAN_FSYNC():
+                try:
+                    faultfs.fsync(fd, path)
+                except OSError:
+                    # fsyncgate: the kernel may mark pages clean after a
+                    # FAILED fsync, so retrying cannot restore durability.
+                    # Latch a sticky fail-stop — every later append/sync
+                    # raises instead of silently claiming durability that
+                    # was never achieved.
+                    with self._io_lock:
+                        self._sync_failed = True
+                        self._dirty.set()
+                    raise
+                finally:
+                    os.close(fd)
             if batch > 0:
                 _OBS_BATCH.observe(batch)
             with self._io_lock:
@@ -290,7 +288,12 @@ class WriteAheadLog:
         the per-batch ack primitive (ROADMAP "group-commit acks").  Returns
         immediately if a group commit already covered ``seq``; otherwise
         joins (or triggers) one fsync instead of a global barrier.  A no-op
-        under the ``"off"`` policy (no durability promised)."""
+        under the ``"off"`` policy (no durability promised).  Timed whole
+        as ``storage_wal_ack``: how long the acking client blocks."""
+        with _SPAN_ACK():
+            self._sync_upto(seq)
+
+    def _sync_upto(self, seq: int) -> None:
         if self.sync_mode == "off" or seq < 0:
             return
         while True:

@@ -1,16 +1,15 @@
-"""PR 9: amplification ledger, trace export, and regression-gate tests.
+"""Amplification ledger and regression-gate tests.
 
 Covers the derived-metrics ledger's byte-exact reconciliation against
 ``IOCounters`` and ``disk_bytes()``, the dead-series gauge rules, span
-outcome recording, Prometheus escaping, Chrome trace export, the
-bench_compare regression gate, and the read-path accounting overhead
-bound (same microbench discipline as PR 8's disabled-trace check).
+outcome recording, Prometheus escaping, the bench_compare regression
+gate, and the read-path accounting overhead bound (same microbench
+discipline as the disabled-path span check in test_obs.py).
 """
 from __future__ import annotations
 
 import dataclasses
 import importlib.util
-import json
 import re
 import time
 from pathlib import Path
@@ -24,7 +23,6 @@ from repro.obs.amplification import (AMP_SCHEMA, LOGICAL_EDGE_BYTES,
                                      AmplificationLedger)
 from repro.obs.export import export_prometheus
 from repro.obs.registry import MetricRegistry
-from repro.obs.trace_export import export_chrome_trace, to_chrome_trace
 
 
 def _ingest(g, n_batches=6, batch=512, seed=0, v=1 << 10):
@@ -220,17 +218,16 @@ def test_registry_remove_and_find():
 # ------------------------------------------------------------ span outcome
 def test_span_exception_records_outcome_and_counter():
     reg = MetricRegistry()
-    reg.enable_tracing(capacity=16)
+    sp = reg.span("store_flush", store="s0")
     with pytest.raises(ValueError):
-        with reg.span("store_flush", store="s0"):
+        with sp:
             raise ValueError("boom")
-    ev = reg.trace_events()[-1]
-    assert ev["name"] == "store_flush" and ev["ok"] is False
+    assert sp.name == "store_flush" and sp.ok is False
     assert reg.counter("store_flush_errors_total", store="s0").value == 1
     # success path: ok True, no extra error count
-    with reg.span("store_flush", store="s0"):
+    with reg.span("store_flush", store="s0") as sp:
         pass
-    assert reg.trace_events()[-1]["ok"] is True
+    assert sp.ok is True
     assert reg.counter("store_flush_errors_total", store="s0").value == 1
     # duration histogram observed BOTH exits
     assert reg.histogram("store_flush_seconds",
@@ -256,46 +253,6 @@ def test_prometheus_escapes_hostile_labels_roundtrip():
     unescaped = (m.group(1).replace("\\n", "\n").replace('\\"', '"')
                  .replace("\\\\", "\\"))
     assert unescaped == hostile
-
-
-# ----------------------------------------------------------- trace export
-def test_chrome_trace_export(tmp_path):
-    reg = MetricRegistry()
-    reg.enable_tracing(capacity=64)
-    with reg.span("store_flush", store="s0"):
-        with reg.span("storage_wal_fsync"):
-            time.sleep(0.001)
-    reg.trace_instant("store_flush_commit", store="s0", fid="3")
-    with pytest.raises(RuntimeError):
-        with reg.span("store_compaction", level="1"):
-            raise RuntimeError("x")
-    doc = to_chrome_trace(reg)
-    evs = doc["traceEvents"]
-    assert any(e["ph"] == "M" and e["name"] == "thread_name" for e in evs)
-    durs = [e for e in evs if e["ph"] == "X"]
-    inst = [e for e in evs if e["ph"] == "i"]
-    assert {e["name"] for e in durs} == {
-        "store_flush", "storage_wal_fsync", "store_compaction"}
-    assert inst[0]["name"] == "store_flush_commit"
-    assert inst[0]["args"]["fid"] == "3"
-    for e in durs + inst:
-        assert isinstance(e["ts"], int) and e["ts"] >= 0
-        assert e["cat"] in ("store", "storage")
-    fsync = next(e for e in durs if e["name"] == "storage_wal_fsync")
-    assert fsync["dur"] >= 1000                       # slept 1 ms
-    bad = next(e for e in durs if e["name"] == "store_compaction")
-    assert bad["args"]["ok"] is False
-    # file form: valid JSON, non-metadata event count returned
-    out = tmp_path / "trace.json"
-    n = export_chrome_trace(str(out), reg)
-    assert n == 4
-    assert json.loads(out.read_text())["traceEvents"]
-
-
-def test_trace_export_empty_ring():
-    reg = MetricRegistry()             # tracing disabled
-    assert to_chrome_trace(reg) == {"traceEvents": [],
-                                    "displayTimeUnit": "ms"}
 
 
 # -------------------------------------------------------- regression gate
@@ -335,9 +292,9 @@ def test_bench_compare_self_passes_inflation_fails():
 
 # ------------------------------------------------------- overhead budget
 def test_read_accounting_overhead_bounded():
-    """The resolve wrapper's additions (3 counter incs + one trace-ring
-    attribute check) must stay far below resolve cost — same discipline
-    as the PR 8 disabled-trace microbench."""
+    """The resolve wrapper's additions (3 counter incs + the
+    ``read_resolve`` span with no profiler session) must stay far below
+    resolve cost — same discipline as the disabled-path span check."""
     from repro.core import LSMGraph
 
     g = LSMGraph(small_store_cfg())
@@ -346,14 +303,13 @@ def test_read_accounting_overhead_bounded():
     def accounting():
         q, p, r = (g._obs_read_queries, g._obs_read_probes,
                     g._obs_read_returned)
-        reg = obs.REGISTRY
+        span = g._span_resolve
         t0 = time.perf_counter()
         for _ in range(n):
-            q.inc(64)
+            with span():
+                q.inc(64)
             p.inc(5)
             r.inc(1280)
-            if reg.trace_ring is not None:
-                pass
         return time.perf_counter() - t0
 
     per_call = min(accounting() for _ in range(3)) / n
