@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels import ops
-from .view import CSRView
+from .view import CSRView, source_ids
 
 _INF = jnp.float32(3.0e38)
 
@@ -27,7 +27,8 @@ def _edge_wt_zero(view: CSRView) -> jnp.ndarray:
 
 
 @functools.partial(jax.jit, static_argnames=("n", "iters"))
-def _pagerank_impl(voff, dst, seg, *, n: int, iters: int, d: float):
+def _pagerank_impl(voff, dst, *, n: int, iters: int, d: float):
+    seg = source_ids(voff, dst.shape[0], n)
     deg = (voff[1:] - voff[:-1]).astype(jnp.float32)
     wt = jnp.ones_like(dst, jnp.float32)
 
@@ -43,12 +44,13 @@ def _pagerank_impl(voff, dst, seg, *, n: int, iters: int, d: float):
 
 
 def pagerank(view: CSRView, iters: int = 20, d: float = 0.85) -> jnp.ndarray:
-    return _pagerank_impl(view.voff, view.dst, view.seg_ids(),
-                          n=view.n_vertices, iters=iters, d=d)
+    return _pagerank_impl(view.voff, view.dst, n=view.n_vertices,
+                          iters=iters, d=d)
 
 
 @functools.partial(jax.jit, static_argnames=("n",))
-def _bfs_impl(voff, dst, seg, src_v, *, n: int):
+def _bfs_impl(voff, dst, src_v, *, n: int):
+    seg = source_ids(voff, dst.shape[0], n)
     dist0 = jnp.full((n,), _INF).at[src_v].set(0.0)
     zero_w = jnp.zeros_like(dst, jnp.float32)
 
@@ -69,12 +71,13 @@ def _bfs_impl(voff, dst, seg, src_v, *, n: int):
 
 def bfs(view: CSRView, source: int) -> jnp.ndarray:
     """Hop distances from source (INF = unreachable)."""
-    return _bfs_impl(view.voff, view.dst, view.seg_ids(),
-                     jnp.asarray(source, jnp.int32), n=view.n_vertices)
+    return _bfs_impl(view.voff, view.dst, jnp.asarray(source, jnp.int32),
+                     n=view.n_vertices)
 
 
 @functools.partial(jax.jit, static_argnames=("n",))
-def _sssp_impl(voff, dst, seg, wts, src_v, *, n: int):
+def _sssp_impl(voff, dst, wts, src_v, *, n: int):
+    seg = source_ids(voff, dst.shape[0], n)
     dist0 = jnp.full((n,), _INF).at[src_v].set(0.0)
 
     def cond(state):
@@ -99,13 +102,13 @@ def sssp(view: CSRView, source: int) -> jnp.ndarray:
     (u, v) of w + dist[v], i.e. paths follow stored edges from u; for the
     usual source-rooted semantics ingest graphs undirected (benchmarks do).
     """
-    return _sssp_impl(view.voff, view.dst, view.seg_ids(),
-                      jnp.maximum(view.prop, 0.0),
+    return _sssp_impl(view.voff, view.dst, jnp.maximum(view.prop, 0.0),
                       jnp.asarray(source, jnp.int32), n=view.n_vertices)
 
 
 @functools.partial(jax.jit, static_argnames=("n",))
-def _cc_impl(voff, dst, seg, *, n: int):
+def _cc_impl(voff, dst, *, n: int):
+    seg = source_ids(voff, dst.shape[0], n)
     label0 = jnp.arange(n, dtype=jnp.float32)
     zero_w = jnp.zeros_like(dst, jnp.float32)
 
@@ -126,11 +129,12 @@ def _cc_impl(voff, dst, seg, *, n: int):
 
 def cc(view: CSRView) -> jnp.ndarray:
     """Connected components by min-label propagation (undirected ingestion)."""
-    return _cc_impl(view.voff, view.dst, view.seg_ids(), n=view.n_vertices)
+    return _cc_impl(view.voff, view.dst, n=view.n_vertices)
 
 
 @functools.partial(jax.jit, static_argnames=("n",))
-def _scan_impl(voff, dst, seg, prop, *, n: int):
+def _scan_impl(voff, dst, prop, *, n: int):
+    seg = source_ids(voff, dst.shape[0], n)
     ones = jnp.ones((n,), jnp.float32)
     deg = ops.gather_segsum(dst, seg, jnp.ones_like(prop), ones, n_out=n)
     wsum = ops.gather_segsum(dst, seg, prop, ones, n_out=n)
@@ -140,5 +144,4 @@ def _scan_impl(voff, dst, seg, prop, *, n: int):
 def scan_stats(view: CSRView) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """SCAN: traverse every vertex's one-hop neighbours (paper's SCAN is the
     substrate of PR/PHP/GNN); returns (degree, Σ edge property) per vertex."""
-    return _scan_impl(view.voff, view.dst, view.seg_ids(), view.prop,
-                      n=view.n_vertices)
+    return _scan_impl(view.voff, view.dst, view.prop, n=view.n_vertices)

@@ -1,12 +1,17 @@
 """Analytics over snapshots: PR/BFS/SSSP/CC/SCAN vs python references."""
 import collections
+import functools
 import heapq
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.analytics import (bfs, cc, materialize_csr, multilevel_pagerank,
                              multilevel_views, pagerank, scan_stats, sssp)
+from repro.analytics.view import source_ids
+from repro.kernels import ops
 from repro.core import LSMGraph
 from repro.data.graphgen import powerlaw_edges
 from conftest import small_store_cfg
@@ -136,7 +141,6 @@ def test_scan_stats(graph):
 def test_analytics_use_pallas_consistent(graph):
     """PageRank's segment-sum inner loop agrees with the same iteration
     over the scatter oracle of kernels/ref.py."""
-    import jax.numpy as jnp
     from repro.kernels import ref
     _, _, view, _, _ = graph
     pr_k = np.asarray(pagerank(view, iters=5))
@@ -149,3 +153,61 @@ def test_analytics_use_pallas_consistent(graph):
         dangling = jnp.sum(jnp.where(deg == 0, x, 0.0))
         x = 0.15 / V + 0.85 * (y + dangling / V)
     assert np.abs(pr_k - np.asarray(x)).max() < 1e-4
+
+
+def _searchsorted_ids(voff):
+    """Per-edge source ids by binary search: the form source_ids replaces."""
+    e = np.arange(int(voff[-1]))
+    return np.minimum(np.searchsorted(voff[1:], e, side="right"),
+                      len(voff) - 2)
+
+
+def _rmat_like_degrees():
+    rng = np.random.default_rng(14)
+    deg = np.minimum(rng.zipf(1.6, 4096), 500)
+    deg[rng.random(4096) < 0.3] = 0
+    return deg
+
+
+DEGREES = {
+    "leading_zero_degree": [0, 0, 0, 3, 1, 2],
+    "zero_degree_run_in_middle": [2, 1, 0, 0, 0, 0, 4, 1],
+    "trailing_zero_degree": [3, 1, 2, 0, 0, 0],
+    "every_edge_on_one_vertex": [0, 0, 7, 0, 0],
+    "one_vertex": [5],
+    "no_edges": [0, 0, 0, 0],
+    "rmat_like_skew": _rmat_like_degrees(),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DEGREES))
+def test_source_ids_match_searchsorted(case):
+    voff = np.r_[0, np.cumsum(DEGREES[case])].astype(np.int32)
+    n_edges, n_vertices = int(voff[-1]), len(voff) - 1
+    got = np.asarray(source_ids(jnp.asarray(voff), n_edges, n_vertices))
+    assert got.dtype == np.int32 and got.shape == (n_edges,)
+    np.testing.assert_array_equal(got, _searchsorted_ids(voff))
+
+
+@functools.partial(jax.jit, static_argnames=("n", "iters"))
+def _pagerank_fed(voff, dst, seg, *, n, iters, d):
+    """_pagerank_impl's iteration with the per-edge source ids given."""
+    deg = (voff[1:] - voff[:-1]).astype(jnp.float32)
+    wt = jnp.ones_like(dst, jnp.float32)
+
+    def body(_, x):
+        y = ops.gather_segsum(dst, seg, wt, x / jnp.maximum(deg, 1.0),
+                              n_out=n)
+        dangling = jnp.sum(jnp.where(deg == 0, x, 0.0))
+        return (1.0 - d) / n + d * (y + dangling / n)
+
+    return jax.lax.fori_loop(0, iters, body,
+                             jnp.full((n,), 1.0 / n, jnp.float32))
+
+
+def test_pagerank_bit_identical_to_searched_ids(graph):
+    _, _, view, _, _ = graph
+    seg = jnp.asarray(_searchsorted_ids(np.asarray(view.voff)), jnp.int32)
+    want = _pagerank_fed(view.voff, view.dst, seg, n=V, iters=10, d=0.85)
+    np.testing.assert_array_equal(np.asarray(pagerank(view, iters=10)),
+                                  np.asarray(want))

@@ -5,9 +5,11 @@ Nothing runs: each kernel is lowered and compiled for one chip of a
 described, not attached, ``v5e:2x2`` topology, which surfaces the TPU
 compiler's refusals without a chip.  Shapes are those of
 ``chip_smoke.py`` at R-MAT scale 20: 2^20 vertices, 2^25 undirected edge
-records.  Each of these kernels is a data-dependent gather at heart,
-which Mosaic does not lower, so each is jnp and must compile as plain
-XLA: no Pallas custom call may appear.
+records.  Each of these kernels is a data-dependent gather or scatter at
+heart, which Mosaic does not lower, so each is jnp and must compile as
+plain XLA: no Pallas custom call may appear.  ``pagerank`` is the whole
+program of an analytics job's PageRank, the expansion of its per-edge
+source ids included.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -20,6 +22,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.analytics import algorithms, view
 from repro.kernels import merge, presence, segment_reduce
 
 V = 1 << 20          # scale-20 vertex space
@@ -72,6 +75,14 @@ def _merge(a, b):
     return merge.merge_streams(a, b)
 
 
+def _source_ids(voff):
+    return view.source_ids(voff, E, V)
+
+
+def _pagerank(voff, dst):
+    return algorithms._pagerank_impl(voff, dst, n=V, iters=10, d=0.85)
+
+
 _I32, _F32, _U32, _BOOL = jnp.int32, jnp.float32, jnp.uint32, jnp.bool_
 _SPINE = (_I32, _I32, _I32, _I32, _BOOL, _F32)  # src dst ts rid marker prop
 
@@ -86,6 +97,8 @@ CASES = {
     "presence_matrix": (presence.presence_matrix,
                         [((RUNS, FILTER_WORDS), _U32), ((RUNS,), _U32),
                          ((QUERIES,), _I32)]),
+    "source_ids": (_source_ids, [((V + 1,), _I32)]),
+    "pagerank": (_pagerank, [((V + 1,), _I32), ((E,), _I32)]),
 }
 
 
